@@ -49,4 +49,6 @@ def run(n_edges: int = 120_000, n_queries: int = 400, seed: int = 0):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     run()

@@ -87,4 +87,6 @@ def run(n_edges: int = 80_000, n_queries: int = 64, seed: int = 0):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     run()

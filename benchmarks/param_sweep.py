@@ -30,4 +30,6 @@ def run(n_edges: int = 60_000, seed: int = 0):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     run()

@@ -233,6 +233,8 @@ def smoke(n_edges: int = 30_000, seed: int = 0,
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     import argparse
 
     ap = argparse.ArgumentParser()

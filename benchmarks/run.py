@@ -7,10 +7,11 @@ Emits ``name,us_per_call,derived`` CSV rows (benchmarks/common.emit).
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="smaller streams (CI)")
@@ -18,6 +19,8 @@ def main() -> None:
                     help="comma-separated module names")
     args = ap.parse_args()
 
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (ablations, accuracy, compound_queries,
                             higgs_perf, irregularity, latency,
                             param_sweep, roofline, space, throughput)
@@ -41,6 +44,7 @@ def main() -> None:
         "roofline": roofline.run,
     }
     only = {s for s in args.only.split(",") if s}
+    broken = []
     for name, fn in suites.items():
         if only and name not in only:
             continue
@@ -50,8 +54,12 @@ def main() -> None:
             fn()
         except Exception as e:  # keep the suite running; report the break
             print(f"{name},0.00,ERROR={type(e).__name__}:{e}", flush=True)
+            broken.append(name)
         print(f"=== {name} done in {time.time() - t0:.1f}s ===", flush=True)
+    if broken:
+        print(f"broken suites: {','.join(broken)}", file=sys.stderr)
+    return 1 if broken else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -112,6 +112,8 @@ def run(seed: int = 0, json_path: str | None = None):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", type=str, default="",
                     help="write machine-readable space metrics here")

@@ -55,7 +55,8 @@ def serial_vs_batched(stream, repeat: int = 1):
     params = {
         # both flags explicit: the comparison must not drift with the
         # HIGGS_BATCHED_INGEST env default the CI matrix flips
-        "serial": HiggsParams(d1=16, F1=19, batched_ingest=False),
+        "serial": HiggsParams(d1=16, F1=19, batched_ingest=False,
+                              insert_backend="host"),
         "batched": HiggsParams(d1=16, F1=19, batched_ingest=True),
     }
     secs, sketches = {}, {}
@@ -430,6 +431,8 @@ def resume_smoke(n_edges: int = 30_000, seed: int = 0,
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny ingestion regression gate (CI)")

@@ -146,4 +146,6 @@ async def live_analysts(src, dst, w, t, ring_edges):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

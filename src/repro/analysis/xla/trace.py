@@ -18,6 +18,7 @@ import math
 import re
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -109,9 +110,9 @@ def _walk_eqns(jaxpr, visit) -> None:
         visit(eqn)
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     _walk_eqns(sub.jaxpr, visit)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     _walk_eqns(sub, visit)
 
 

@@ -1,39 +1,19 @@
-"""Version-compat shims for jax APIs that moved between releases.
-
-The codebase targets current jax (``jax.shard_map``, ``check_vma``,
-``jax.sharding.AxisType``) but must also run on 0.4.x wheels where those
-live under ``jax.experimental.shard_map`` / ``check_rep`` and explicit
-axis types don't exist yet.  Call sites use these helpers instead of
-branching on version themselves.
-"""
+"""Thin wrappers over the jax sharding APIs, so every call site builds
+meshes and ``shard_map`` programs the same way."""
 from __future__ import annotations
 
 import jax
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicit Auto axis types when supported,
-    falling back to a hand-built ``Mesh`` on wheels predating it."""
-    if not hasattr(jax, "make_mesh"):
-        from jax.experimental import mesh_utils
-        devs = mesh_utils.create_device_mesh(tuple(axis_shapes),
-                                             devices=devices)
-        return jax.sharding.Mesh(devs, tuple(axis_names))
-    kwargs = {}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axis_names)
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    kwargs = {"axis_types": (jax.sharding.AxisType.Auto,) * len(axis_names)}
     if devices is not None:
         kwargs["devices"] = devices
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` without varying-manual-axes checking, falling back
-    to ``jax.experimental.shard_map`` (``check_rep``) on older jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` without varying-manual-axes checking."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -235,10 +235,11 @@ class HiggsSketch(LegacyQueryMixin):
         if env in ("host", "vector", "pallas"):
             if env == "pallas" and not (params.use_ob and
                                         params.batched_ingest):
-                # the pallas kernel spills to overflow blocks from the
-                # batched drain; incompatible params fall back to host
-                # (explicit insert_backend="pallas" still raises)
-                return "host"
+                # the same refusal as an explicit insert_backend="pallas"
+                raise ValueError(
+                    "HIGGS_INSERT_BACKEND=pallas requires use_ob and "
+                    "batched_ingest (spills must go to overflow blocks, "
+                    "not recursive leaves)")
             return env
         import jax
         return "vector" if jax.default_backend() == "tpu" else "host"
@@ -910,11 +911,11 @@ class HiggsSketch(LegacyQueryMixin):
     def _build_parents_fused(self, level: int, u0: int, m: int) -> None:
         """Device-resident aggregation cascade step (device pool storage).
 
-        One fused launch (`kernels/pipeline.py::_aggregate_step`) slices
-        the ready theta-child block out of the child pool's live slabs,
+        One fused launch (`kernels/pipeline.py::_aggregate_step`) takes
+        the ready theta-child block sliced off the child pool's live slabs,
         recovers leaf coordinates, computes round orders and places all
-        ``m`` parents directly into the *donated* parent slabs — the
-        child block never crosses to host (``_maybe_aggregate`` chains
+        ``m`` parents, which are appended to the *donated* parent slabs —
+        the child block never crosses to host (``_maybe_aggregate`` chains
         one such launch per ready level per drain).  Only the small
         spill mask is fetched; the canonical spill columns stay lazy
         device arrays and materialize only when the mask is non-empty.

@@ -211,24 +211,43 @@ def xla_entry_points():
 
     def build_aggregate_fused():
         from repro.kernels.pipeline import _aggregate_step
-        # production shapes: theta-child block sliced from the level-1
-        # slabs (cap 64), parents scattered into the donated level-2
-        # slabs; the overflow columns are the only tensor h2d operands
-        level, mp, cap_c, cap_p, obp = 1, 2, 64, 16, 16
-        dp = p.d(level + 1)
-        pshape = (cap_p, dp, dp, b)
-        pslabs = (sds(pshape, u32), sds(pshape, u32), sds(pshape, f32),
-                  sds(pshape, u32), sds(pshape, u32))
-        cshape = (cap_c, d, d, b)
-        cslabs = (sds(cshape, u32), sds(cshape, u32), sds(cshape, f32),
-                  sds(cshape, u32))
+        # production shapes: the theta-child block of two level-1
+        # parents, sliced from the level-1 slabs; the overflow columns
+        # are the only tensor h2d operand
+        level, mp, obp = 1, 2, 16
+        block = (sds((mp * p.theta, d, d, b), u32),
+                 sds((mp * p.theta, d, d, b), u32),
+                 sds((mp * p.theta, d, d, b), f32),
+                 sds((mp * p.theta, d, d, b), u32))
         ob_pack = sds((6, mp, obp), u32)
-        cases = [TraceCase("l1_m2_cap64",
-                           (*pslabs, *cslabs, ob_pack,
-                            sds((), i32), sds((), i32), sds((), i32)),
+        cases = [TraceCase("l1_m2", (*block, ob_pack),
                            {"mp": mp, "theta": p.theta, "level": level,
                             "params": p})]
         return _aggregate_step, ("mp", "theta", "level", "params"), cases
+
+    # the row moves around the step, at the pool capacities of a
+    # 1,096,440-edge wiki-talk stream (1682 leaves: level-1 slabs hold
+    # 2048 rows, level-2 slabs 512)
+    def build_take_rows():
+        from repro.kernels.pipeline import _take_rows
+        mp, cap = 2, 2048
+        slab = (cap, d, d, b)
+        args = (sds(slab, u32), sds(slab, u32), sds(slab, f32),
+                sds(slab, u32), sds((), i32))
+        cases = [TraceCase(f"l1_cap{cap}_rows{mp * p.theta}", args,
+                           {"rows": mp * p.theta})]
+        return _take_rows, ("rows",), cases
+
+    def build_append_rows():
+        from repro.kernels.pipeline import _append_rows
+        mp, cap, dp = 2, 512, p.d(2)
+        dts = (u32, u32, f32, u32, u32)
+        slabs = tuple(sds((cap, dp, dp, b), dt) for dt in dts)
+        parents = NodeState(*(sds((mp, dp, dp, b), dt) for dt in dts))
+        cases = [TraceCase(f"l2_cap{cap}_m{mp}",
+                           (*slabs, parents, sds((), i32), sds((), i32)),
+                           {})]
+        return _append_rows, (), cases
 
     interp = frozenset({"interpret"})
     return [
@@ -264,16 +283,27 @@ def xla_entry_points():
                    build_insert_chunks_vector,
                    host_args=tuple(range(10)), fetch_output=True,
                    expected_compile_keys=1),
-        # the fused aggregation cascade: parent slabs donated, child
-        # slabs device-resident; only the packed OB staging block (a
-        # host structure, six uint32 rows like ingest's raw staging)
-        # + three scalars cross h2d, and nothing returns but the small
-        # spill mask (fetched separately, outside this launch's output
-        # contract).  Replaces the retired
-        # kernels.aggregate_children_vector entry — the standalone
-        # vector launch survives only inside this step, and host-storage
-        # backends aggregate through the numpy twin with no XLA site.
+        # the fused aggregation cascade: the child block is sliced off
+        # the device-resident slabs and the parents are appended to the
+        # donated parent slabs by two small capacity-keyed launches
+        # (pipeline._take_rows/_append_rows, which take the three
+        # position scalars); this step's only tensor h2d operand is the
+        # packed OB staging block (a host structure, six uint32 rows like
+        # ingest's raw staging), and nothing returns but the small spill
+        # mask (fetched separately, outside this launch's output
+        # contract).  Host-storage backends aggregate through the numpy
+        # twin with no XLA site.
         EntryPoint("kernels.aggregate_fused", build_aggregate_fused,
-                   host_args=(9, 10, 11, 12),
+                   host_args=(4,),
                    fetch_output=False, expected_compile_keys=1),
+        # the cascade's row moves: the child block leaves the donated
+        # child slabs from a host offset, the parents land in the donated
+        # parent slabs at a host offset and count; only those scalars
+        # cross, nothing returns
+        EntryPoint("kernels.aggregate_take_rows", build_take_rows,
+                   host_args=(4,), fetch_output=False,
+                   expected_compile_keys=1),
+        EntryPoint("kernels.aggregate_append_rows", build_append_rows,
+                   host_args=(6, 7), fetch_output=False,
+                   expected_compile_keys=1),
     ]

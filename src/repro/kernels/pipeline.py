@@ -39,6 +39,16 @@ from repro.core.params import HiggsParams
 from repro.kernels import leaf_insert as _li
 
 
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
+def _append_rows(fp_s, fp_d, w, t, idx, nodes: NodeState, n0, m):
+    """Scatter the first ``m`` of ``nodes`` into the donated slabs at rows
+    ``n0..n0+m``; the other rows go out of range and are dropped."""
+    li = jnp.arange(nodes.fp_s.shape[0], dtype=jnp.int32)
+    tgt = jnp.where(li < m, n0 + li, jnp.int32(fp_s.shape[0]))
+    return tuple(slab.at[tgt].set(vals, mode="drop")
+                 for slab, vals in zip((fp_s, fp_d, w, t, idx), nodes))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("r", "F1", "d1", "b", "seed",
                                     "interpret"),
@@ -69,52 +79,53 @@ def _ingest_step(fp_s, fp_d, w, t, idx, stage, lengths, n0, nl, *,
     nodes, spill = _li.leaf_insert_batched_pallas(
         nodes, fs, fd, rows, cols, wf, tt.astype(jnp.uint32), valid,
         r=r, interpret=interpret)
-    li = jnp.arange(lead, dtype=jnp.int32)
-    # rows past the live leaf count (and anything else out of range)
-    # scatter to cap and are dropped
-    tgt = jnp.where(li < nl, n0 + li, jnp.int32(fp_s.shape[0]))
-    slabs = tuple(
-        slab.at[tgt].set(vals, mode="drop")
-        for slab, vals in zip((fp_s, fp_d, w, t, idx), nodes))
+    slabs = _append_rows(fp_s, fp_d, w, t, idx, nodes, n0, nl)
     spill_mask = jnp.where(valid, spill, 0)
     return slabs + (spill_mask,)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("mp", "theta", "level", "params"),
-                   donate_argnums=(0, 1, 2, 3, 4))
-def _aggregate_step(pfp_s, pfp_d, pw, pt, pidx,
-                    c_fp_s, c_fp_d, c_w, c_idx,
-                    ob_pack, i0, n0, m, *,
-                    mp: int, theta: int, level: int, params):
-    """Fused aggregation step over donated parent-pool slabs.
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _take_rows(fp_s, fp_d, w, idx, i0, *, rows: int):
+    """``rows`` consecutive slab rows from physical offset ``i0`` (traced)
+    — the ready child block of one aggregation step.  Rows past the live
+    count come back as garbage; the step drops what they produce."""
+    at = i0 + jnp.arange(rows, dtype=jnp.int32)
+    return tuple(jnp.take(s, at, axis=0) for s in (fp_s, fp_d, w, idx))
 
-    pfp_s..pidx: (cap_p, dp, dp, b) parent-level slabs (donated, returned
-    updated).  c_*: (cap_c, d, d, b) child-level slabs, read-only.
+
+@functools.partial(jax.jit,
+                   static_argnames=("mp", "theta", "level", "params"))
+def _aggregate_step(c_fp_s, c_fp_d, c_w, c_idx, ob_pack, *,
+                    mp: int, theta: int, level: int, params):
+    """Fused aggregation step: build ``mp`` parents from their children.
+
+    c_*: (mp * theta, d, d, b) child block (:func:`_take_rows`).
     ob_pack: (6, mp, ob_pad) uint32 host-staged overflow columns —
     f1s/f1d/bs/bd, weight bits, validity — packed as ONE tensor like the
     ingest staging block (the overflow store is a host structure;
-    zero-width when no child carries OB entries).
-    i0/n0/m: traced scalars (child-block physical offset, parent append
-    offset, live parent count) so per-drain positions never enter the
-    compile cache key; ``mp`` is the pow2-padded parent count bounding
-    jit shape variety exactly like the host batched path.
+    zero-width when no child carries OB entries).  ``mp`` is the
+    pow2-padded parent count bounding jit shape variety exactly like the
+    host batched path.  No operand depends on a pool's capacity, so this
+    costly program compiles once per (mp, level, ob_pad), not again at
+    every slab growth; :func:`_take_rows` and :func:`_append_rows` move
+    the rows.
 
     Bit-identical to :meth:`HiggsSketch._build_parents_batched`'s host
     reference: the device ``recover_leaf_coords``/``coords_at_level``
     twins are exact, invalid entries get the same zeroed coordinates,
     and ``cmatrix.round_orders`` reproduces ``host_round_orders``'s
     stable permutation, so ``aggregate_children_pre`` places the same
-    entries in the same rounds.  Garbage rows read for pad parents
-    (clamped takes past the ready block) scatter to ``cap_p`` and drop.
+    entries in the same rounds.
+
+    Returns (parent NodeState (mp, dp, dp, b), spill mask, canonical
+    spill columns f1s, f1d, base_s, base_d, w).
     """
     d, b = c_fp_s.shape[1], c_fp_s.shape[3]
     per = theta * d * d * b
-    idx = i0 + jnp.arange(mp * theta, dtype=jnp.int32)
-    e_fs = jnp.take(c_fp_s, idx, axis=0).reshape(mp, per)
-    e_fd = jnp.take(c_fp_d, idx, axis=0).reshape(mp, per)
-    e_w = jnp.take(c_w, idx, axis=0).reshape(mp, per)
-    e_idx = jnp.take(c_idx, idx, axis=0).reshape(mp, per)
+    e_fs = c_fp_s.reshape(mp, per)
+    e_fd = c_fp_d.reshape(mp, per)
+    e_w = c_w.reshape(mp, per)
+    e_idx = c_idx.reshape(mp, per)
     grid = jnp.arange(d, dtype=jnp.uint32)
     shape5 = (mp, theta, d, d, b)
     e_row = jnp.broadcast_to(grid[None, None, :, None, None],
@@ -149,16 +160,9 @@ def _aggregate_step(pfp_s, pfp_d, pw, pt, pidx,
     state4, wmat, spill = cmatrix.aggregate_children_pre(
         fp_s_p, fp_d_p, rows_p, cols_p, w_all, e_valid, orders,
         params, level)
-
-    li = jnp.arange(mp, dtype=jnp.int32)
-    tgt = jnp.where(li < m, n0 + li, jnp.int32(pfp_s.shape[0]))
-    slabs = tuple(
-        slab.at[tgt].set(vals, mode="drop")
-        for slab, vals in zip(
-            (pfp_s, pfp_d, pw, pt, pidx),
-            (state4[:, 0], state4[:, 1], wmat,
-             state4[:, 2], state4[:, 3])))
-    return slabs + (spill, f1s, f1d, base_s, base_d, w_all)
+    parents = NodeState(state4[:, 0], state4[:, 1], wmat, state4[:, 2],
+                        state4[:, 3])
+    return parents, spill, f1s, f1d, base_s, base_d, w_all
 
 
 class DrainPipeline:
@@ -223,9 +227,9 @@ class DrainPipeline:
 
     def aggregate(self, child_pool, parent_pool, level: int, u0: int,
                   m: int, ob):
-        """Build ``m`` ready parents at ``level`` in one fused launch
-        against the donated parent slabs — the device-resident twin of
-        the host batched aggregation (no ``gather_block`` fetch).
+        """Build ``m`` ready parents at ``level`` in one fused launch and
+        append them to the donated parent slabs — the device-resident twin
+        of the host batched aggregation (no ``gather_block`` fetch).
 
         ``ob`` is the host-stacked overflow-column dict from
         :meth:`HiggsSketch._gather_child_obs_stacked` (or ``None``),
@@ -252,16 +256,16 @@ class DrainPipeline:
                 ob_pack[row, :m] = ob[k]
             ob_pack[4, :m] = ob["w"].view(np.uint32)
             ob_pack[5, :m] = ob["valid"]
-        out = _aggregate_step(
-            pslabs["fp_s"], pslabs["fp_d"], pslabs["w"], pslabs["t"],
-            pslabs["idx"],
+        block = _take_rows(
             cslabs["fp_s"], cslabs["fp_d"], cslabs["w"], cslabs["idx"],
-            jnp.asarray(ob_pack),
-            np.int32(u0 * theta - child_pool.base),
-            np.int32(parent_pool.n), np.int32(m),
-            mp=mp, theta=theta, level=level, params=p)
-        parent_pool.adopt_slabs(dict(zip(NodeState._fields, out[:5])), m)
+            np.int32(u0 * theta - child_pool.base), rows=mp * theta)
+        parents, spill, *coords = _aggregate_step(
+            *block, jnp.asarray(ob_pack), mp=mp, theta=theta, level=level,
+            params=p)
+        slabs = _append_rows(
+            pslabs["fp_s"], pslabs["fp_d"], pslabs["w"], pslabs["t"],
+            pslabs["idx"], parents, np.int32(parent_pool.n), np.int32(m))
+        parent_pool.adopt_slabs(dict(zip(NodeState._fields, slabs)), m)
         # the only mandatory d2h of the cascade level: the spill mask
         # feeding the host overflow store
-        spill = np.asarray(out[5])[:m].astype(bool)
-        return spill, out[6:]
+        return np.asarray(spill)[:m].astype(bool), coords

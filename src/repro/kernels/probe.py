@@ -2,14 +2,19 @@
 
 TPU adaptation (DESIGN.md §3): arbitrary per-query gathers are hostile to
 the TPU vector unit, so the probe is reformulated *gather-free* — each
-grid step streams one (matrix, row-tile) block through VMEM and compares
-every bucket against every query, restricting positions with one-hot
-row/column candidate masks built from an iota.  FLOPs go up by ~d/r on the
-VPU, HBM traffic is a single stream over the matrix pool (the actual
-bottleneck), and the access pattern is fully sequential.
+grid step streams one (matrix, row-tile) block through VMEM and, query
+by query, sums the weights of every matching entry, restricting
+positions with row/column candidate masks built from an iota.  FLOPs go
+up by ~d/r on the VPU, HBM traffic is a single stream over the matrix
+pool (the actual bottleneck), and the access pattern is fully
+sequential.
 
-Grid: (m, d / TR).  Outputs are accumulated across grid steps into the
-same (q,) block (index_map constant in both grid axes).
+Layout (what Mosaic accepts): each matrix field is viewed as a
+lane-dense ``(d, d*b)`` tile (element ``(row, col*b + slot)``), the
+query scalars and the node mask live in SMEM, and the ``(1, q)`` output
+row accumulates across the whole grid.
+
+Grid: (m, d / TR).
 """
 from __future__ import annotations
 
@@ -18,90 +23,109 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.cmatrix import NodeState
 from repro.kernels.leaf_insert import default_interpret
 
 
-def _edge_kernel(mask_ref, fs_ref, fd_ref, rows_ref, cols_ref, ts_ref,
-                 te_ref, mfs_ref, mfd_ref, mw_ref, mt_ref, out_ref,
-                 *, match_time: bool, tr: int):
-    mi = pl.program_id(0)
+def _row_mask(ch_ref, off, j, r, tr, row0):
+    """(tr, 1): rows of this tile that are among query j's candidates
+    ``ch_ref[off:off + r, j]``."""
+    row_of = jax.lax.broadcasted_iota(jnp.int32, (tr, 1), 0) + row0
+    return functools.reduce(jnp.logical_or,
+                            [row_of == ch_ref[off + i, j] for i in range(r)])
+
+
+def _col_mask(ch_ref, off, j, r, b, d):
+    """(1, d*b): lanes whose column is among query j's candidates."""
+    col_of = jax.lax.broadcasted_iota(jnp.int32, (1, d * b), 1) // b
+    return functools.reduce(jnp.logical_or,
+                            [col_of == ch_ref[off + i, j] for i in range(r)])
+
+
+def _probe_grid(mask_ref, tt_ref, out_ref, match_time, mt_ref, hit_of):
+    """Shared grid body: zero the output on the first step, then for a
+    live node add each query's sum of matching weights to its lane."""
     ti = pl.program_id(1)
 
-    @pl.when((mi == 0) & (ti == 0))
+    @pl.when((pl.program_id(0) == 0) & (ti == 0))
     def _init():
         out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-    mfs = mfs_ref[0]                       # (tr, d, b)
-    mfd = mfd_ref[0]
-    mw = mw_ref[0]
-    tr_, d, b = mfs.shape
-    node_ok = mask_ref[mi] != 0
+    @pl.when(mask_ref[0, 0, 0] != 0)
+    def _probe():
+        in_range = None
+        if match_time:
+            mt = mt_ref[0]
+            in_range = (mt >= tt_ref[0]) & (mt <= tt_ref[1])
+        lane_q = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
 
-    rows = rows_ref[...]                   # (q, r)
-    cols = cols_ref[...]
-    q, r = rows.shape
-    # one-hot candidate masks; rows are global indices, this block covers
-    # [ti*tr, ti*tr + tr)
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (q, r, tr), 2) + ti * tr
-    row_mask = jnp.any(rows[:, :, None] == row_iota, axis=1)   # (q, tr)
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (q, r, d), 2)
-    col_mask = jnp.any(cols[:, :, None] == col_iota, axis=1)   # (q, d)
+        def body(j, acc):
+            hit, mw = hit_of(j, ti)
+            if in_range is not None:
+                hit &= in_range
+            c = jnp.sum(jnp.where(hit, mw, 0.0), axis=(0, 1), keepdims=True)
+            return acc + jnp.where(lane_q == j, c, 0.0)
 
-    fs = fs_ref[...]
-    fd = fd_ref[...]
-    match = (mfs[None] == fs[:, None, None, None]) & \
-        (mfd[None] == fd[:, None, None, None])                 # (q,tr,d,b)
-    if match_time:
-        mt = mt_ref[0]
-        match &= (mt[None] >= ts_ref[...][:, None, None, None]) & \
-            (mt[None] <= te_ref[...][:, None, None, None])
-    pos = row_mask[:, :, None, None] & col_mask[:, None, :, None]
-    contrib = jnp.where(match & pos & node_ok, mw[None], 0.0)
-    out_ref[...] += contrib.sum(axis=(1, 2, 3))
+        q = out_ref.shape[1]
+        out_ref[...] += jax.lax.fori_loop(
+            0, q, body, jnp.zeros(out_ref.shape, jnp.float32))
 
 
-def _vertex_kernel(mask_ref, fv_ref, rows_ref, ts_ref, te_ref,
-                   mfp_ref, mw_ref, mt_ref, out_ref,
-                   *, match_time: bool, tr: int, direction: str):
-    mi = pl.program_id(0)
-    ti = pl.program_id(1)
+def _edge_kernel(mask_ref, fp_ref, ch_ref, tt_ref, mfs_ref, mfd_ref, mw_ref,
+                 mt_ref, out_ref, *, match_time: bool, tr: int, r: int,
+                 b: int):
+    def hit_of(j, ti):
+        mfs, mfd = mfs_ref[0], mfd_ref[0]
+        d = mfs.shape[1] // b
+        hit = ((mfs == fp_ref[0, j]) & (mfd == fp_ref[1, j])
+               & _row_mask(ch_ref, 0, j, r, tr, ti * tr)
+               & _col_mask(ch_ref, r, j, r, b, d))
+        return hit, mw_ref[0]
 
-    @pl.when((mi == 0) & (ti == 0))
-    def _init():
-        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+    _probe_grid(mask_ref, tt_ref, out_ref, match_time, mt_ref, hit_of)
 
-    mfp = mfp_ref[0]                       # (tr, d, b) fp_s or fp_d
-    mw = mw_ref[0]
-    tr_, d, b = mfp.shape
-    node_ok = mask_ref[mi] != 0
 
-    rows = rows_ref[...]                   # (q, r) candidate rows/cols
-    q, r = rows.shape
-    if direction == "out":
-        # candidates restrict the first matrix axis (tiled)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (q, r, tr), 2) + ti * tr
-        pos = jnp.any(rows[:, :, None] == iota, axis=1)        # (q, tr)
-        pos = pos[:, :, None, None]
-    else:
-        # candidates restrict the second (column) axis (not tiled)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (q, r, d), 2)
-        pos = jnp.any(rows[:, :, None] == iota, axis=1)        # (q, d)
-        pos = pos[:, None, :, None]
+def _vertex_kernel(mask_ref, fp_ref, ch_ref, tt_ref, mfp_ref, mw_ref, mt_ref,
+                   out_ref, *, match_time: bool, tr: int, r: int, b: int,
+                   direction: str):
+    def hit_of(j, ti):
+        mfp = mfp_ref[0]
+        if direction == "out":
+            # candidates restrict the first matrix axis (tiled)
+            pos = _row_mask(ch_ref, 0, j, r, tr, ti * tr)
+        else:
+            # candidates restrict the second (column) axis (not tiled)
+            pos = _col_mask(ch_ref, 0, j, r, b, mfp.shape[1] // b)
+        return (mfp == fp_ref[0, j]) & pos, mw_ref[0]
 
-    fv = fv_ref[...]
-    match = mfp[None] == fv[:, None, None, None]
-    if match_time:
-        mt = mt_ref[0]
-        match &= (mt[None] >= ts_ref[...][:, None, None, None]) & \
-            (mt[None] <= te_ref[...][:, None, None, None])
-    contrib = jnp.where(match & pos & node_ok, mw[None], 0.0)
-    out_ref[...] += contrib.sum(axis=(1, 2, 3))
+    _probe_grid(mask_ref, tt_ref, out_ref, match_time, mt_ref, hit_of)
 
 
 def _row_tile(d: int) -> int:
     return min(d, max(8, 512 // max(d // 8, 1)))
+
+
+def _probe_call(kernel, nodes: NodeState, fields, node_mask, fp, ch, ts, te,
+                interpret):
+    m, d, _, b = nodes.fp_s.shape
+    q = fp.shape[1]
+    tr = _row_tile(d)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    mat_spec = pl.BlockSpec((1, tr, d * b), lambda mi, ti: (mi, ti, 0))
+    tt = jnp.stack([jnp.asarray(ts, jnp.uint32), jnp.asarray(te, jnp.uint32)])
+    out = pl.pallas_call(
+        functools.partial(kernel, tr=tr, b=b),
+        grid=(m, d // tr),
+        in_specs=[smem((1, 1, 1), lambda mi, ti: (mi, 0, 0)),
+                  smem(), smem(), smem()] + [mat_spec] * len(fields),
+        out_specs=pl.BlockSpec((1, q), lambda mi, ti: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, q), jnp.float32),
+        interpret=interpret,
+    )(jnp.asarray(node_mask, jnp.int32).reshape(m, 1, 1), fp, ch, tt,
+      *(f.reshape(m, d, d * b) for f in fields))
+    return out[0]
 
 
 def edge_probe_pallas(nodes: NodeState, node_mask, fs, fd, rows, cols,
@@ -111,29 +135,14 @@ def edge_probe_pallas(nodes: NodeState, node_mask, fs, fd, rows, cols,
     :func:`repro.core.cmatrix.probe_edge`."""
     if interpret is None:
         interpret = default_interpret()
-    m, d, _, b = nodes.fp_s.shape
-    q, r = rows.shape
-    tr = _row_tile(d)
-    grid = (m, d // tr)
-    qspec = pl.BlockSpec((q,), lambda mi, ti: (0,))
-    q2spec = pl.BlockSpec((q, r), lambda mi, ti: (0, 0))
-    mspec = pl.BlockSpec((1, tr, d, b), lambda mi, ti: (mi, ti, 0, 0))
-    maskspec = pl.BlockSpec((m,), lambda mi, ti: (0,))
-    kernel = functools.partial(_edge_kernel, match_time=match_time, tr=tr)
-    ts = jnp.broadcast_to(jnp.asarray(ts, jnp.uint32), (q,))
-    te = jnp.broadcast_to(jnp.asarray(te, jnp.uint32), (q,))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[maskspec, qspec, qspec, q2spec, q2spec, qspec, qspec,
-                  mspec, mspec, mspec, mspec],
-        out_specs=pl.BlockSpec((q,), lambda mi, ti: (0,)),
-        out_shape=jax.ShapeDtypeStruct((q,), jnp.float32),
-        interpret=interpret,
-    )(jnp.asarray(node_mask, jnp.int32), jnp.asarray(fs, jnp.uint32),
-      jnp.asarray(fd, jnp.uint32), jnp.asarray(rows, jnp.int32),
-      jnp.asarray(cols, jnp.int32), ts, te,
-      nodes.fp_s, nodes.fp_d, nodes.w, nodes.t)
+    r = rows.shape[1]
+    fp = jnp.stack([jnp.asarray(fs, jnp.uint32), jnp.asarray(fd, jnp.uint32)])
+    ch = jnp.concatenate([jnp.asarray(rows, jnp.int32).T,
+                          jnp.asarray(cols, jnp.int32).T])
+    kernel = functools.partial(_edge_kernel, match_time=match_time, r=r)
+    return _probe_call(kernel, nodes,
+                       (nodes.fp_s, nodes.fp_d, nodes.w, nodes.t),
+                       node_mask, fp, ch, ts, te, interpret)
 
 
 def vertex_probe_pallas(nodes: NodeState, node_mask, fv, rows, ts, te, *,
@@ -143,27 +152,11 @@ def vertex_probe_pallas(nodes: NodeState, node_mask, fv, rows, ts, te, *,
     :func:`repro.core.cmatrix.probe_vertex`."""
     if interpret is None:
         interpret = default_interpret()
-    m, d, _, b = nodes.fp_s.shape
-    q, r = rows.shape
-    tr = _row_tile(d)
-    grid = (m, d // tr)
-    qspec = pl.BlockSpec((q,), lambda mi, ti: (0,))
-    q2spec = pl.BlockSpec((q, r), lambda mi, ti: (0, 0))
-    mspec = pl.BlockSpec((1, tr, d, b), lambda mi, ti: (mi, ti, 0, 0))
-    maskspec = pl.BlockSpec((m,), lambda mi, ti: (0,))
-    kernel = functools.partial(_vertex_kernel, match_time=match_time,
-                               tr=tr, direction=direction)
-    ts = jnp.broadcast_to(jnp.asarray(ts, jnp.uint32), (q,))
-    te = jnp.broadcast_to(jnp.asarray(te, jnp.uint32), (q,))
-    fp = nodes.fp_s if direction == "out" else nodes.fp_d
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[maskspec, qspec, q2spec, qspec, qspec,
-                  mspec, mspec, mspec],
-        out_specs=pl.BlockSpec((q,), lambda mi, ti: (0,)),
-        out_shape=jax.ShapeDtypeStruct((q,), jnp.float32),
-        interpret=interpret,
-    )(jnp.asarray(node_mask, jnp.int32), jnp.asarray(fv, jnp.uint32),
-      jnp.asarray(rows, jnp.int32), ts, te,
-      fp, nodes.w, nodes.t)
+    r = rows.shape[1]
+    fp = jnp.asarray(fv, jnp.uint32)[None]
+    ch = jnp.asarray(rows, jnp.int32).T
+    kernel = functools.partial(_vertex_kernel, match_time=match_time, r=r,
+                               direction=direction)
+    fpm = nodes.fp_s if direction == "out" else nodes.fp_d
+    return _probe_call(kernel, nodes, (fpm, nodes.w, nodes.t), node_mask,
+                       fp, ch, ts, te, interpret)

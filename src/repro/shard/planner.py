@@ -214,7 +214,7 @@ class ShardedQueryPlanner:
         res = sm.run_stacked(ops.edge_probe_stacked, nodes, mask, fs_l,
                              fd_l, rows, cols, np.uint32(ts),
                              np.uint32(te), match_time=filter_time)
-        part = np.asarray(res, np.float64)[:, :q]    # (k, q)
+        part = np.asarray(res, np.float64)[:len(live), :q]
         sel = np.stack([route[s] for s, _ in live])  # (k, q)
         return (part * sel).sum(axis=0)
 
@@ -232,6 +232,12 @@ class ShardedQueryPlanner:
         pad = _pow2_pad(max(len(ids) for _, ids in live))
         gathered = [sm.shards[s].pools[level - 1].gather(ids, pad)
                     for s, ids in live]
+        if sm.mesh is not None:
+            # pad the shard axis to whole devices with masked-off rows
+            # (sliced away by the callers): every launch spans the mesh
+            n_pad = -len(gathered) % sm.mesh.devices.size
+            gathered += [(gathered[0][0],
+                          jnp.zeros_like(gathered[0][1]))] * n_pad
         nodes = type(gathered[0][0])(
             *(jnp.stack([getattr(g[0], name) for g in gathered])
               for name in type(gathered[0][0])._fields))
@@ -301,6 +307,6 @@ class ShardedQueryPlanner:
         res = sm.run_stacked(ops.vertex_probe_stacked, nodes, mask, f_l,
                              rows, np.uint32(ts), np.uint32(te),
                              direction="in", match_time=filter_time)
-        part = np.asarray(res, np.float64)[:, :q]    # (k, q)
+        part = np.asarray(res, np.float64)[:len(live), :q]
         sel = np.stack([route[s] for s, _ in live])  # (k, q)
         return (part * sel).sum(axis=0)

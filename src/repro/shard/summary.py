@@ -230,16 +230,19 @@ class ShardedHiggs(LegacyQueryMixin):
             f.result()                 # surface the first worker error
 
     def place_stacked(self, nodes, mask):
-        """Device placement for a stacked (S, ...) probe batch: shard the
-        leading axis across the device mesh when one is available; the
-        single-device identity fallback keeps CPU hosts untouched."""
+        """Device placement for a stacked (k, ...) probe batch: shard the
+        leading axis across the device mesh when one is available; hosts
+        without a mesh keep the batch where it is."""
         if self.mesh is None:
             return nodes, mask
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
-        k = nodes.fp_s.shape[0]
-        if k % self.mesh.devices.size:
-            return nodes, mask         # unpadded remainder: keep local
+        k, ndev = nodes.fp_s.shape[0], self.mesh.devices.size
+        if k % ndev:
+            raise ValueError(
+                f"stacked probe batch of {k} shards does not split over "
+                f"the {ndev}-device mesh (the planner pads it to whole "
+                f"devices)")
         spec = NamedSharding(self.mesh, PartitionSpec("shard"))
         return (jax.device_put(nodes, spec), jax.device_put(mask, spec))
 
@@ -252,8 +255,7 @@ class ShardedHiggs(LegacyQueryMixin):
         1-D ``("shard",)`` mesh, query operands replicate, and each
         device vmaps only its resident pool slice — arithmetic is
         per-shard-independent, so the stacked (k, q) output is
-        bit-identical to the plain launch.  Falls back to the plain
-        launch when the leading axis doesn't divide the mesh."""
+        bit-identical to the plain launch."""
         if self._mode != "shard_map":
             return fn(nodes, mask, *args, **static)
         import functools
@@ -261,9 +263,6 @@ class ShardedHiggs(LegacyQueryMixin):
         from jax.sharding import PartitionSpec
 
         from repro import compat
-        ndev = self.mesh.devices.size
-        if nodes.fp_s.shape[0] % ndev:
-            return fn(nodes, mask, *args, **static)
         shard, rep = PartitionSpec("shard"), PartitionSpec()
         mapped = compat.shard_map(
             functools.partial(fn, **static), mesh=self.mesh,

@@ -208,6 +208,16 @@ class TestPoolStorageSeam:
         assert HiggsSketch(HiggsParams(**kw_for("pallas")))._storage \
             == "device"
 
+    @pytest.mark.parametrize("kw", [dict(batched_ingest=False),
+                                    dict(use_ob=False)])
+    def test_env_pallas_backend_rejects_incompatible_params(self,
+                                                            monkeypatch, kw):
+        # the environment selects the backend exactly like the parameter
+        # does: no quiet fall-back to another engine
+        monkeypatch.setenv("HIGGS_INSERT_BACKEND", "pallas")
+        with pytest.raises(ValueError, match="HIGGS_INSERT_BACKEND=pallas"):
+            HiggsSketch(HiggsParams(**kw))
+
     def test_adopt_slabs_device_only(self):
         pool = _LevelPool(4, 2, storage="host")
         with pytest.raises(ValueError, match="device storage"):

@@ -66,7 +66,8 @@ class TestSerialBatchedEquivalence:
     @pytest.mark.parametrize("seed,chunks", [(0, 1), (1, 5), (2, 3)])
     def test_random_streams(self, seed, chunks):
         stream = make_stream(1500, 60, 2000, seed)
-        ref = build(HiggsParams(batched_ingest=False, **PARAMS_SMALL),
+        ref = build(HiggsParams(batched_ingest=False, insert_backend="host",
+                                **PARAMS_SMALL),
                     stream, chunks)
         got = build(HiggsParams(insert_backend="host", **PARAMS_SMALL),
                     stream, chunks)
@@ -75,7 +76,8 @@ class TestSerialBatchedEquivalence:
     def test_oversize_timestamp_runs(self):
         # t_max << n/chunk forces runs far longer than a chunk
         stream = make_stream(900, 40, 6, 3)
-        ref = build(HiggsParams(batched_ingest=False, **PARAMS_SMALL),
+        ref = build(HiggsParams(batched_ingest=False, insert_backend="host",
+                                **PARAMS_SMALL),
                     stream, 4)
         got = build(HiggsParams(insert_backend="host", **PARAMS_SMALL),
                     stream, 4)
@@ -84,7 +86,8 @@ class TestSerialBatchedEquivalence:
 
     def test_vector_backend_matches(self):
         stream = make_stream(800, 50, 1200, 4)
-        ref = build(HiggsParams(batched_ingest=False, **PARAMS_SMALL),
+        ref = build(HiggsParams(batched_ingest=False, insert_backend="host",
+                                **PARAMS_SMALL),
                     stream, 3)
         got = build(HiggsParams(insert_backend="vector", **PARAMS_SMALL),
                     stream, 3)
@@ -93,7 +96,8 @@ class TestSerialBatchedEquivalence:
     def test_mmb_disabled_matches(self):
         kw = dict(d1=4, F1=14, b=2, r=1, use_mmb=False)
         stream = make_stream(600, 40, 800, 5)
-        ref = build(HiggsParams(batched_ingest=False, **kw), stream, 2)
+        ref = build(HiggsParams(batched_ingest=False, insert_backend="host",
+                                **kw), stream, 2)
         got = build(HiggsParams(**kw), stream, 2)
         assert_sketch_equal(ref, got, "no mmb")
 
@@ -165,7 +169,7 @@ class TestDrainEdgeCases:
         """With use_ob=False spills recursively open new leaves; the
         batched flag must fall back to the serial closer and still match
         the reference bit for bit."""
-        kw = dict(d1=4, F1=14, b=2, r=2, use_ob=False)
+        kw = dict(d1=4, F1=14, b=2, r=2, use_ob=False, insert_backend="host")
         stream = make_stream(800, 30, 40, 8)       # heavy runs -> spills
         ref = build(HiggsParams(batched_ingest=False, **kw), stream, 3)
         got = build(HiggsParams(batched_ingest=True, **kw), stream, 3)
@@ -322,7 +326,8 @@ def test_property_serial_batched_equivalence():
     @given(streams())
     def check(case):
         stream, chunks = case
-        ref = build(HiggsParams(batched_ingest=False, **PARAMS_SMALL),
+        ref = build(HiggsParams(batched_ingest=False, insert_backend="host",
+                                **PARAMS_SMALL),
                     stream, chunks)
         got = build(HiggsParams(**PARAMS_SMALL), stream, chunks)
         assert_sketch_equal(ref, got)

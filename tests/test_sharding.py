@@ -104,10 +104,12 @@ class TestPartition:
         # code, which must never execute in a forked worker
         with pytest.raises(ValueError, match="jax-free drain"):
             ShardedHiggs(shards=2, parallel="process", d1=4, F1=14,
-                         b=2, r=2, batched_ingest=False)
+                         b=2, r=2, batched_ingest=False,
+                         insert_backend="host")
         with pytest.raises(ValueError, match="jax-free drain"):
             ShardedHiggs(shards=2, parallel="process", d1=4, F1=14,
-                         b=2, r=2, batched_ingest=True, use_ob=False)
+                         b=2, r=2, batched_ingest=True, use_ob=False,
+                         insert_backend="host")
 
     def test_dst_map_bounds(self):
         with pytest.raises(ValueError):
@@ -144,7 +146,8 @@ class TestPerShardBitEquality:
         the same per-shard sketches (tiny stream: the reference path
         pays one launch per leaf)."""
         stream = make_stream(600, 40, 400, 9)
-        p = HiggsParams(d1=4, F1=14, b=2, r=2, batched_ingest=False)
+        p = HiggsParams(d1=4, F1=14, b=2, r=2, batched_ingest=False,
+                        insert_backend="host")
         sh = ShardedHiggs(shards=2, parallel="none", params=p)
         sh.insert(*stream)
         sh.flush()
