@@ -119,7 +119,7 @@ def fused_aggregate_speedup(n_edges: int = 20_000, seed: int = 0,
     from repro.core.higgs import HiggsSketch
     from repro.core.params import HiggsParams
     from repro.core.pool import _LevelPool
-    from repro.kernels.pipeline import DrainPipeline
+    from repro.kernels.pipeline import DrainPipeline, pack_ob
     from repro.stream.generator import lkml_like_stream
 
     p = HiggsParams(d1=16, F1=19, insert_backend="pallas",
@@ -134,12 +134,13 @@ def fused_aggregate_speedup(n_edges: int = 20_000, seed: int = 0,
     assert m >= 2, "stream too small to form an aggregation block"
     u0 = child.base // theta
     ob = sk._gather_child_obs_stacked(1, u0, m)
+    ob_pack = pack_ob(ob, m)
     pipe = DrainPipeline(p)
 
     def run_fused():
         parent = _LevelPool(p.d(2), p.b, storage="device")
         t0 = time.perf_counter()
-        pipe.aggregate(child, parent, 1, u0, m, ob)
+        pipe.aggregate(child, parent, 1, u0, m, ob_pack)
         jax.block_until_ready(parent.device_slabs()["w"])
         return time.perf_counter() - t0
 

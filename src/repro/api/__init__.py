@@ -2,7 +2,8 @@
 
 * :mod:`repro.api.queries` — typed, batched query descriptions
   (``EdgeQuery``/``VertexQuery``/``PathQuery``/``SubgraphQuery``) and the
-  ``QueryResult``/``QueryStats`` return types.
+  ``QueryResult``/``QueryStats`` return types, and the ingest path's
+  ``IngestStats`` counters.
 * :mod:`repro.api.protocol` — the formal ``GraphSummary`` protocol plus the
   pointwise/batched adapter mixins.
 * :mod:`repro.api.planner` — the batched query-plan engine for HIGGS.
@@ -15,15 +16,15 @@ from repro.api.handle import SummaryHandle
 from repro.api.planner import QueryPlanner
 from repro.api.protocol import (GraphSummary, LegacyQueryMixin,
                                 PointwiseQueryMixin, SnapshotMixin)
-from repro.api.queries import (EdgeQuery, PathQuery, Query, QueryBatch,
-                               QueryResult, QueryStats, SubgraphQuery,
-                               VertexQuery)
+from repro.api.queries import (EdgeQuery, IngestStats, PathQuery, Query,
+                               QueryBatch, QueryResult, QueryStats,
+                               SubgraphQuery, VertexQuery)
 from repro.api.registry import (available_summaries, build_summary,
                                 make_summary, register, restore_summary)
 
 __all__ = [
     "EdgeQuery", "VertexQuery", "PathQuery", "SubgraphQuery",
-    "Query", "QueryBatch", "QueryResult", "QueryStats",
+    "Query", "QueryBatch", "QueryResult", "QueryStats", "IngestStats",
     "GraphSummary", "LegacyQueryMixin", "PointwiseQueryMixin",
     "SnapshotMixin", "QueryPlanner", "SummaryHandle",
     "make_summary", "build_summary", "register", "available_summaries",

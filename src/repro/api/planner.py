@@ -40,6 +40,7 @@ from repro.api.queries import (EDGE_LOWERED, QueryBatch, QueryResult,
 from repro.core import cmatrix
 from repro.core.cmatrix import NodeState
 from repro.core.cmatrix import pow2_pad as _pow2_pad
+from repro.runtime.trace import spanned
 
 if TYPE_CHECKING:  # avoid a circular import; higgs imports this module
     from repro.core.higgs import HiggsSketch
@@ -292,6 +293,7 @@ class QueryPlanner:
 
     # -- device probes ---------------------------------------------------
 
+    @spanned("higgs.probe")
     def _probe_level_edge(self, level, ids, f1s, bs, f1d, bd, ts, te,
                           filter_time, stats: QueryStats):
         sk = self.sketch
@@ -315,6 +317,7 @@ class QueryPlanner:
                                 match_time=filter_time)
         return np.asarray(res, np.float64)[:q]
 
+    @spanned("higgs.probe")
     def _probe_level_vertex(self, level, ids, f1, base, ts, te, direction,
                             filter_time, stats: QueryStats):
         sk = self.sketch
@@ -342,6 +345,7 @@ class QueryPlanner:
     # (also composed by repro.shard.planner.ShardedQueryPlanner, whose
     # stacked fan-in path pairs each shard's plan with these OB scans)
 
+    @spanned("higgs.ob_scan")
     def _ob_edge(self, level, ids, f1s, bs, f1d, bd, ts, te, filter_time,
                  stats: QueryStats):
         ob = self.sketch.ob
@@ -362,6 +366,7 @@ class QueryPlanner:
             out += (m * rec["w"][None, :]).sum(axis=1)
         return out
 
+    @spanned("higgs.ob_scan")
     def _ob_vertex(self, level, ids, f1, base, ts, te, direction,
                    filter_time, stats: QueryStats):
         ob = self.sketch.ob

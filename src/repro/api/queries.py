@@ -173,6 +173,48 @@ class QueryStats:
 
 
 @dataclasses.dataclass
+class IngestStats:
+    """Running accounting of the ingest path, held by the summary as
+    ``ingest_stats`` (telemetry: never serialized, not carried by an
+    epoch replica).  Plain integer increments, exact and deterministic
+    for a given stream and configuration; a harness reads deltas between
+    two snapshots (:meth:`snapshot`).
+
+    * ``inserts`` — ``insert`` calls; ``drains`` — drains that closed
+      at least one leaf; ``leaves_closed`` — leaves those drains closed.
+    * ``launches`` — device programs the drain dispatches: the fused
+      ingest step, per cascade level ``_take_rows`` + ``_aggregate_step``
+      + ``_append_rows``, and one per slab field an eviction slides on
+      device.
+    * ``fetches``/``fetch_bytes`` — blocking device-to-host copies of
+      the drain (``repro.runtime.trace.fetch``): the ingest and cascade
+      spill masks, and the spill coordinates of a spilling level.
+    * ``staged_bytes`` — host-to-device bytes of the drain's staging
+      block and overflow pack.
+    * ``spill_items`` — entries the drain routed to overflow blocks.
+    * ``pool_grows``/``pool_grow_bytes`` — level-pool capacity growths
+      and the bytes of capacity each allocated.
+    * ``slides`` — retention slides of a level pool (one per level a
+      segment leaves).
+    """
+    inserts: int = 0
+    drains: int = 0
+    leaves_closed: int = 0
+    launches: int = 0
+    fetches: int = 0
+    fetch_bytes: int = 0
+    staged_bytes: int = 0
+    spill_items: int = 0
+    pool_grows: int = 0
+    pool_grow_bytes: int = 0
+    slides: int = 0
+
+    def snapshot(self) -> dict:
+        """The counters as a plain dict (subtract two for a delta)."""
+        return dataclasses.asdict(self)
+
+
+@dataclasses.dataclass
 class QueryResult:
     """Results aligned with the query batch plus execution stats.
 

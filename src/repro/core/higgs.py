@@ -20,13 +20,16 @@ import numpy as np
 from repro.analysis.sanitize import maybe_check as _sanitize_check
 from repro.api.planner import QueryPlanner
 from repro.api.protocol import LegacyQueryMixin
-from repro.api.queries import QueryBatch, QueryResult
+from repro.api.queries import IngestStats, QueryBatch, QueryResult
 from repro.core import cmatrix, hashing
 from repro.core.cmatrix import EMPTY, NodeState
 from repro.core.cmatrix import pow2_pad as _pow2_pad
 from repro.core.params import HiggsParams
 from repro.core.pool import _LevelPool
 from repro.core.segments import SegmentStore
+from repro.runtime.trace import fetch as _fetch
+from repro.runtime.trace import span as _span
+from repro.runtime.trace import spanned as _spanned
 
 
 class _LeafIndex:
@@ -201,18 +204,21 @@ class HiggsSketch(LegacyQueryMixin):
     snapshot_kind = "higgs"
     # rebuilt from params / restored via the probe_counter property —
     # intentionally not serialized (higgslint R3); _pinned marks an
-    # epoch replica (a restored sketch is always writable again)
+    # epoch replica (a restored sketch is always writable again);
+    # ingest_stats is telemetry and starts afresh
     _SNAPSHOT_DERIVED = ("_probe_base", "_chunk_pad", "_backend",
-                         "_storage", "_pipeline", "_pinned")
+                         "_storage", "_pipeline", "_pinned",
+                         "ingest_stats")
 
     def __init__(self, params: HiggsParams = HiggsParams()):
         self.params = params
         self._backend = self._resolve_backend(params)
         self._storage = self._resolve_storage(params, self._backend)
         self._pipeline = None     # lazy fused-drain pipeline (pallas+device)
+        self.ingest_stats = IngestStats()          # ingest-path counters
         self.pools: list[_LevelPool] = [
-            _LevelPool(params.d1, params.b,
-                       storage=self._storage)]     # level 1 (leaves)
+            _LevelPool(params.d1, params.b, storage=self._storage,
+                       stats=self.ingest_stats)]   # level 1 (leaves)
         self._leaves = _LeafIndex()
         self.ob = _OverflowStore()
         self._buf: list[np.ndarray] = []           # pending raw items
@@ -306,6 +312,7 @@ class HiggsSketch(LegacyQueryMixin):
             "segments": self.segments.epoch_stamp(),
         }
 
+    @_spanned("higgs.pin")
     def _pin_replica(self) -> "HiggsSketch":
         """Read-only replica frozen at the current ``structure_version``.
 
@@ -335,6 +342,7 @@ class HiggsSketch(LegacyQueryMixin):
             rep._backend = self._backend
             rep._storage = self._storage
             rep._pipeline = None
+            rep.ingest_stats = IngestStats()
             rep.pools = [pool.pin_view() for pool in self.pools]
             rep._leaves = self._leaves.pin_view()
             rep.ob = self.ob.pin_view()
@@ -424,7 +432,8 @@ class HiggsSketch(LegacyQueryMixin):
         for lvl, pm in enumerate(meta["pools"], start=1):
             if lvl > len(self.pools):
                 self.pools.append(_LevelPool(int(pm["d"]), int(pm["b"]),
-                                             storage=self._storage))
+                                             storage=self._storage,
+                                             stats=self.ingest_stats))
             self.pools[lvl - 1].load(
                 {name: arrays[f"pool{lvl}/{name}"]
                  for name in NodeState._fields},
@@ -449,6 +458,7 @@ class HiggsSketch(LegacyQueryMixin):
     # insertion
     # ------------------------------------------------------------------
 
+    @_spanned("higgs.insert")
     def insert(self, src, dst, w, t) -> None:
         """Insert a batch of stream items (arrival order, t non-decreasing).
 
@@ -466,6 +476,7 @@ class HiggsSketch(LegacyQueryMixin):
         self._buf.append(batch)
         self._buf_len += batch.shape[1]
         self.n_items += batch.shape[1]
+        self.ingest_stats.inserts += 1
         self._drain(final=False)
 
     def flush(self) -> None:
@@ -485,13 +496,36 @@ class HiggsSketch(LegacyQueryMixin):
 
         Chunk boundaries are a deterministic function of the buffered item
         sequence alone (never of how ``insert`` batched it), so the span
-        scan below is equivalent to the legacy one-leaf-per-iteration loop;
-        closing then happens for all spans in one batched launch (or
-        serially per span on the reference path).
+        scan (:meth:`_split_pending`) is equivalent to the legacy
+        one-leaf-per-iteration loop; closing then happens for all spans in
+        one batched launch (or serially per span on the reference path).
         """
         cs = self.params.chunk_size
         if self._buf_len < cs and not (final and self._buf_len > 0):
             return
+        with _span("higgs.drain"):
+            with _span("higgs.drain.split"):
+                buf, spans = self._split_pending(final)
+            if not spans:
+                return
+            self.ingest_stats.drains += 1
+            # the OB ablation re-opens spill leaves recursively, which
+            # must interleave with leaf order — only the serial path can
+            # do that
+            if self.params.batched_ingest and self.params.use_ob:
+                self._close_leaves_batched(buf, spans)
+            else:
+                for s, e in spans:
+                    self._close_leaf(buf[:, s:e])
+            if self.segments.active:
+                self._lifecycle()
+            _sanitize_check(self)
+
+    def _split_pending(self, final: bool):
+        """Concatenate the pending buffer and cut it into leaf spans;
+        the items past the last span stay pending.  Returns
+        ``(buf, spans)``."""
+        cs = self.params.chunk_size
         buf = np.concatenate(self._buf, axis=1) if len(self._buf) > 1 \
             else self._buf[0]
         ts_col = buf[3]
@@ -530,18 +564,7 @@ class HiggsSketch(LegacyQueryMixin):
             self._buf_len = int(rest.shape[1])
         else:
             self._buf = [buf]          # keep concatenated for the next call
-        if not spans:
-            return
-        # the OB ablation re-opens spill leaves recursively, which must
-        # interleave with leaf order — only the serial path can do that
-        if self.params.batched_ingest and self.params.use_ob:
-            self._close_leaves_batched(buf, spans)
-        else:
-            for s, e in spans:
-                self._close_leaf(buf[:, s:e])
-        if self.segments.active:
-            self._lifecycle()
-        _sanitize_check(self)
+        return buf, spans
 
     def _close_leaf(self, chunk: np.ndarray) -> None:
         p = self.params
@@ -568,6 +591,7 @@ class HiggsSketch(LegacyQueryMixin):
             padded(w, np.float32), padded(t, np.uint32),
             jnp.asarray(valid), p)
         leaf_id = self.pools[0].base + self.pools[0].append(node)
+        self.ingest_stats.leaves_closed += 1
         self._leaves.append(int(t[0]), int(t[-1]))
         self._t_last = max(self._t_last, int(t[-1]))
         k = int(n_spill)
@@ -577,6 +601,7 @@ class HiggsSketch(LegacyQueryMixin):
         self._version += 1
 
         if k:
+            self.ingest_stats.spill_items += k
             s_hs = np.asarray(spill["hs"][:k])
             s_hd = np.asarray(spill["hd"][:k])
             if p.use_ob:
@@ -665,6 +690,7 @@ class HiggsSketch(LegacyQueryMixin):
             w_sp = np.asarray(w_merged)
 
         base = self.pools[0].base + self.pools[0].append_batch(host, nl)
+        self.ingest_stats.leaves_closed += nl
         starts = t_full[[s - s0 for s, _ in spans]]
         ends = t_full[[e - 1 - s0 for _, e in spans]]
         self._leaves.extend(starts, ends)
@@ -673,18 +699,20 @@ class HiggsSketch(LegacyQueryMixin):
         self._version += nl
 
         if spill_mask.any():
-            for i in range(nl):
-                idxs = np.nonzero(spill_mask[i])[0]
-                if not len(idxs):
-                    continue
-                s_hs = hs[i, idxs]
-                s_hd = hd[i, idxs]
-                self.ob.add(1, base + i,
-                            f1s=s_hs & p.fp_mask, f1d=s_hd & p.fp_mask,
-                            bs=(s_hs >> p.F1) % p.d1,
-                            bd=(s_hd >> p.F1) % p.d1,
-                            w=w_sp[i, idxs].astype(np.float64),
-                            t=t[i, idxs])
+            self.ingest_stats.spill_items += int(spill_mask.sum())
+            with _span("higgs.drain.spill"):
+                for i in range(nl):
+                    idxs = np.nonzero(spill_mask[i])[0]
+                    if not len(idxs):
+                        continue
+                    s_hs = hs[i, idxs]
+                    s_hd = hd[i, idxs]
+                    self.ob.add(1, base + i,
+                                f1s=s_hs & p.fp_mask, f1d=s_hd & p.fp_mask,
+                                bs=(s_hs >> p.F1) % p.d1,
+                                bd=(s_hd >> p.F1) % p.d1,
+                                w=w_sp[i, idxs].astype(np.float64),
+                                t=t[i, idxs])
         self._maybe_aggregate()
 
     def _insert_leaves_pallas(self, hs, hd, w, t, valid):
@@ -732,11 +760,12 @@ class HiggsSketch(LegacyQueryMixin):
         lead = _pow2_pad(nl, lo=1)
         if self._pipeline is None:
             from repro.kernels.pipeline import DrainPipeline
-            self._pipeline = DrainPipeline(p)
+            self._pipeline = DrainPipeline(p, self.ingest_stats)
         pool = self.pools[0]
         base_slot, spill_mask, stage = self._pipeline.ingest(
             pool, buf, spans, lead, pad)
         base = pool.base + base_slot
+        self.ingest_stats.leaves_closed += nl
         starts = buf[3, [s for s, _ in spans]]
         ends = buf[3, [e - 1 for _, e in spans]]
         self._leaves.extend(starts, ends)
@@ -745,20 +774,22 @@ class HiggsSketch(LegacyQueryMixin):
         self._version += nl
 
         if spill_mask.any():
-            for i in range(nl):
-                idxs = np.nonzero(spill_mask[i])[0]
-                if not len(idxs):
-                    continue
-                s_hs = hashing.np_mix32(stage[0, i, idxs], p.seed)
-                s_hd = hashing.np_mix32(stage[1, i, idxs],
-                                        p.seed ^ 0x5BD1E995)
-                self.ob.add(1, base + i,
-                            f1s=s_hs & p.fp_mask, f1d=s_hd & p.fp_mask,
-                            bs=(s_hs >> p.F1) % p.d1,
-                            bd=(s_hd >> p.F1) % p.d1,
-                            w=stage[2, i, idxs].view(np.float32)
-                            .astype(np.float64),
-                            t=stage[3, i, idxs])
+            self.ingest_stats.spill_items += int(spill_mask.sum())
+            with _span("higgs.drain.spill"):
+                for i in range(nl):
+                    idxs = np.nonzero(spill_mask[i])[0]
+                    if not len(idxs):
+                        continue
+                    s_hs = hashing.np_mix32(stage[0, i, idxs], p.seed)
+                    s_hd = hashing.np_mix32(stage[1, i, idxs],
+                                            p.seed ^ 0x5BD1E995)
+                    self.ob.add(1, base + i,
+                                f1s=s_hs & p.fp_mask, f1d=s_hd & p.fp_mask,
+                                bs=(s_hs >> p.F1) % p.d1,
+                                bd=(s_hd >> p.F1) % p.d1,
+                                w=stage[2, i, idxs].view(np.float32)
+                                .astype(np.float64),
+                                t=stage[3, i, idxs])
         self._maybe_aggregate()
 
     # ------------------------------------------------------------------
@@ -786,12 +817,13 @@ class HiggsSketch(LegacyQueryMixin):
                 # the leaf closings that triggered this cascade already
                 # bumped _version this drain
                 self.pools.append(  # higgslint: disable=R5
-                    _LevelPool(p.d(level + 1), p.b,
-                               storage=self._storage))
-            if p.batched_ingest:
-                self._build_parents_batched(level, parent_n, n_ready)
-            else:
-                self._build_parents_serial(level)
+                    _LevelPool(p.d(level + 1), p.b, storage=self._storage,
+                               stats=self.ingest_stats))
+            with _span("higgs.cascade"):
+                if p.batched_ingest:
+                    self._build_parents_batched(level, parent_n, n_ready)
+                else:
+                    self._build_parents_serial(level)
             level += 1
 
     def _build_parents_serial(self, level: int) -> None:
@@ -810,6 +842,7 @@ class HiggsSketch(LegacyQueryMixin):
             self.pools[level].append(parent)  # higgslint: disable=R5
             k = int(n_spill)
             if k:
+                self.ingest_stats.spill_items += k
                 self.ob.add(level + 1, u,
                             f1s=np.asarray(spill["f1s"][:k]),
                             f1d=np.asarray(spill["f1d"][:k]),
@@ -864,7 +897,8 @@ class HiggsSketch(LegacyQueryMixin):
             e_col, e_fd, e_idx, level, p, "d")
         w_all = e_w.astype(np.float32)
 
-        ob = self._gather_child_obs_stacked(level, u0, m)
+        with _span("higgs.cascade.ob"):
+            ob = self._gather_child_obs_stacked(level, u0, m)
         if ob is not None:
             f1s = np.concatenate([f1s, ob["f1s"]], axis=1)
             f1d = np.concatenate([f1d, ob["f1d"]], axis=1)
@@ -899,14 +933,16 @@ class HiggsSketch(LegacyQueryMixin):
         spill_h = np.asarray(spill)
         if not spill_h.any():
             return
-        for i in range(m):
-            idxs = np.nonzero(spill_h[i])[0]
-            if len(idxs):
-                self.ob.add(level + 1, u0 + i,
-                            f1s=f1s[i, idxs], f1d=f1d[i, idxs],
-                            bs=base_s[i, idxs], bd=base_d[i, idxs],
-                            w=w_all[i, idxs].astype(np.float64),
-                            t=np.zeros((len(idxs),), np.uint32))
+        self.ingest_stats.spill_items += int(spill_h.sum())
+        with _span("higgs.drain.spill"):
+            for i in range(m):
+                idxs = np.nonzero(spill_h[i])[0]
+                if len(idxs):
+                    self.ob.add(level + 1, u0 + i,
+                                f1s=f1s[i, idxs], f1d=f1d[i, idxs],
+                                bs=base_s[i, idxs], bd=base_d[i, idxs],
+                                w=w_all[i, idxs].astype(np.float64),
+                                t=np.zeros((len(idxs),), np.uint32))
 
     def _build_parents_fused(self, level: int, u0: int, m: int) -> None:
         """Device-resident aggregation cascade step (device pool storage).
@@ -921,26 +957,30 @@ class HiggsSketch(LegacyQueryMixin):
         device arrays and materialize only when the mask is non-empty.
         Bit-identical to the host-storage reference path above.
         """
+        from repro.kernels.pipeline import DrainPipeline, pack_ob
         pool = self.pools[level - 1]
-        ob = self._gather_child_obs_stacked(level, u0, m)
+        with _span("higgs.cascade.ob"):
+            ob_pack = pack_ob(self._gather_child_obs_stacked(level, u0, m),
+                              m)
         if self._pipeline is None:
-            from repro.kernels.pipeline import DrainPipeline
-            self._pipeline = DrainPipeline(self.params)
+            self._pipeline = DrainPipeline(self.params, self.ingest_stats)
         # covered by the leaf-closing version bump earlier in this drain
         spill_h, coords = self._pipeline.aggregate(  # higgslint: disable=R5
-            pool, self.pools[level], level, u0, m, ob)
+            pool, self.pools[level], level, u0, m, ob_pack)
         if not spill_h.any():
             return
-        f1s, f1d, base_s, base_d, w_all = (np.asarray(a)[:m]
-                                           for a in coords)
-        for i in range(m):
-            idxs = np.nonzero(spill_h[i])[0]
-            if len(idxs):
-                self.ob.add(level + 1, u0 + i,
-                            f1s=f1s[i, idxs], f1d=f1d[i, idxs],
-                            bs=base_s[i, idxs], bd=base_d[i, idxs],
-                            w=w_all[i, idxs].astype(np.float64),
-                            t=np.zeros((len(idxs),), np.uint32))
+        self.ingest_stats.spill_items += int(spill_h.sum())
+        with _span("higgs.drain.spill"):
+            f1s, f1d, base_s, base_d, w_all = (
+                a[:m] for a in _fetch(tuple(coords), self.ingest_stats))
+            for i in range(m):
+                idxs = np.nonzero(spill_h[i])[0]
+                if len(idxs):
+                    self.ob.add(level + 1, u0 + i,
+                                f1s=f1s[i, idxs], f1d=f1d[i, idxs],
+                                bs=base_s[i, idxs], bd=base_d[i, idxs],
+                                w=w_all[i, idxs].astype(np.float64),
+                                t=np.zeros((len(idxs),), np.uint32))
 
     def _gather_child_obs_stacked(self, level: int, u0: int, m: int):
         """Overflow columns for ``m`` theta-blocks of children as stacked
@@ -998,6 +1038,7 @@ class HiggsSketch(LegacyQueryMixin):
     # temporal lifecycle: sealing, eviction, coarsening compaction
     # ------------------------------------------------------------------
 
+    @_spanned("higgs.lifecycle")
     def _lifecycle(self) -> None:
         """Seal completed segments, then enforce the retention policy.
 
@@ -1042,6 +1083,7 @@ class HiggsSketch(LegacyQueryMixin):
                 self.ob.drop(lvl, node)  # higgslint: disable=R5
             pool.drop_prefix(cnt)  # higgslint: disable=R5
 
+    @_spanned("higgs.evict")
     def _evict_front(self) -> None:
         """Evict the oldest retained segment wholesale: its slabs at
         every resident level, its overflow keys, and (for fine
@@ -1058,6 +1100,7 @@ class HiggsSketch(LegacyQueryMixin):
         st.items_evicted += seg.n_items
         self._version += 1                 # invalidate memoized plans
 
+    @_spanned("higgs.evict")
     def _coarsen_oldest_fine(self) -> None:
         """Collapse the oldest fine segment into its retained root: drop
         its leaves and mid-level ancestors (plus their overflow keys and
@@ -1091,6 +1134,7 @@ class HiggsSketch(LegacyQueryMixin):
     # boundary search (paper Alg. 3) — canonical theta-ary decomposition
     # ------------------------------------------------------------------
 
+    @_spanned("higgs.plan")
     def boundary_search(self, ts: int, te: int):
         """Decompose [ts, te] into (plan, filtered_leaves):
 

@@ -173,10 +173,13 @@ class _LevelPool:
     explicit fetch barriers.
     """
 
-    def __init__(self, d: int, b: int, storage: str = "host"):
+    def __init__(self, d: int, b: int, storage: str = "host",
+                 stats=None):
         if storage not in _STORAGES:
             raise ValueError(f"unknown pool storage {storage!r}")
         self.d, self.b = d, b
+        # the owning summary's IngestStats (grows and slides), or None
+        self.stats = stats
         self.n = 0
         self.cap = 0
         self.base = 0
@@ -233,11 +236,21 @@ class _LevelPool:
         self.n -= k
         self.base += k
         self._dirty()
+        if self.stats is not None:
+            self.stats.slides += 1
+            if self._st.kind == "device":
+                self.stats.launches += len(NodeState._fields)
 
     def _grow(self, new_cap: int) -> None:
         self._st.grow(self.n, new_cap)
         self.cap = new_cap
         self._dirty()
+        if self.stats is not None:
+            # five 4-byte fields per matrix cell
+            self.stats.pool_grows += 1
+            self.stats.pool_grow_bytes += (new_cap * self.d * self.d
+                                           * self.b * 4
+                                           * len(NodeState._fields))
 
     def reserve(self, need: int) -> None:
         """Grow capacity (power-of-two schedule) to hold ``need`` nodes

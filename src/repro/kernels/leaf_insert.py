@@ -137,6 +137,7 @@ def leaf_insert_batched_pallas(nodes: NodeState, fs, fd, rows, cols, w, t,
         + (jax.ShapeDtypeStruct((L, 1, n), jnp.int32),),
         input_output_aliases={1: 0, 2: 1, 3: 2, 4: 3, 5: 4},
         interpret=interpret,
+        name="higgs_leaf_insert",
     )
     *out, spill = fn(pk, *mats)
     fields = (o.reshape(L, d, d, b) if f == "w" else

@@ -35,6 +35,7 @@ import asyncio
 import dataclasses
 
 from repro.api.queries import QueryBatch, QueryResult
+from repro.runtime.trace import spanned
 from repro.serve.epoch import ReadEpoch, epoch_of
 
 
@@ -204,6 +205,7 @@ class SummaryService:
                 for _ in jobs:
                     self._queue.task_done()
 
+    @spanned("higgs.serve.round")
     def _serve_round(self, jobs: list) -> None:
         """Execute one coalesced round: merge every drained caller's
         batch, answer it with ONE epoch query (one planner execution —
