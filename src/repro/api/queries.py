@@ -184,7 +184,7 @@ class IngestStats:
       at least one leaf; ``leaves_closed`` — leaves those drains closed.
     * ``launches`` — device programs the drain dispatches: the fused
       ingest step, per cascade level ``_take_rows`` + ``_aggregate_step``
-      + ``_append_rows``, and one per slab field an eviction slides on
+      + ``_append_rows``, and one per level pool an eviction slides on
       device.
     * ``fetches``/``fetch_bytes`` — blocking device-to-host copies of
       the drain (``repro.runtime.trace.fetch``): the ingest and cascade

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -42,6 +43,20 @@ def _empty_device_slabs(n: int, d: int, b: int) -> dict:
             if name in ("fp_s", "fp_d")
             else jnp.zeros(shape, jnp.float32 if name == "w" else jnp.uint32)
             for name in NodeState._fields}
+
+
+@jax.jit
+def _slide_slabs(slabs: dict, n, k) -> dict:
+    """Rows ``[0, n - k)`` of every slab take rows ``[k, n)``; rows from
+    ``n - k`` on keep their contents, as ``HostPoolStorage.slide``
+    leaves them.  ``n`` and ``k`` are traced int32 scalars, so one
+    program serves every node count of a pool's capacity.  Not donated:
+    the input slabs stay valid."""
+    cap = next(iter(slabs.values())).shape[0]
+    rows = jnp.arange(cap, dtype=jnp.int32)
+    src = jnp.where(rows < n - k, rows + k, rows)
+    return {name: jnp.take(slab, src, axis=0, mode="clip")
+            for name, slab in slabs.items()}
 
 
 class HostPoolStorage:
@@ -132,9 +147,7 @@ class DevicePoolStorage:
             for name in NodeState._fields}
 
     def slide(self, n: int, k: int) -> None:
-        self.slabs = {name: self.slabs[name].at[: n - k].set(
-            self.slabs[name][k:n])
-            for name in NodeState._fields}
+        self.slabs = _slide_slabs(self.slabs, np.int32(n), np.int32(k))
 
     def host_view(self) -> Optional[dict]:
         if self.slabs is None:
@@ -239,7 +252,7 @@ class _LevelPool:
         if self.stats is not None:
             self.stats.slides += 1
             if self._st.kind == "device":
-                self.stats.launches += len(NodeState._fields)
+                self.stats.launches += 1
 
     def _grow(self, new_cap: int) -> None:
         self._st.grow(self.n, new_cap)

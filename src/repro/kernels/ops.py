@@ -249,6 +249,16 @@ def xla_entry_points():
                            {})]
         return _append_rows, (), cases
 
+    # the eviction slide of one level pool at the level-1 capacity of
+    # the same stream: the node count and the drop count are traced
+    def build_pool_slide():
+        from repro.core.pool import _slide_slabs
+        cap = 2048
+        slabs = node((cap,))._asdict()
+        cases = [TraceCase(f"l1_cap{cap}",
+                           (slabs, sds((), i32), sds((), i32)), {})]
+        return _slide_slabs, (), cases
+
     interp = frozenset({"interpret"})
     return [
         # pallas leaf insertion: chunks arrive as host numpy (w/t/valid;
@@ -305,5 +315,10 @@ def xla_entry_points():
                    expected_compile_keys=1),
         EntryPoint("kernels.aggregate_append_rows", build_append_rows,
                    host_args=(6, 7), fetch_output=False,
+                   expected_compile_keys=1),
+        # the retention slide of a device pool: only the two counts
+        # cross, nothing returns
+        EntryPoint("kernels.pool_slide", build_pool_slide,
+                   host_args=(1, 2), fetch_output=False,
                    expected_compile_keys=1),
     ]

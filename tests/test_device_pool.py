@@ -4,6 +4,7 @@ pools is bit-identical to the host-storage build across drain, flush,
 retention, and snapshot boundaries.  Hypothesis drives the stream
 shapes and the batch splits so leaf/drain boundaries land everywhere.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -16,7 +17,8 @@ from repro.api.queries import EdgeQuery, VertexQuery
 from repro.core.cmatrix import NodeState
 from repro.core.higgs import HiggsSketch
 from repro.core.params import HiggsParams, RetentionPolicy
-from repro.core.pool import _LevelPool
+from repro.core.pool import (DevicePoolStorage, HostPoolStorage,
+                             _LevelPool, _slide_slabs)
 
 SETTINGS = dict(max_examples=10, deadline=None)
 
@@ -240,3 +242,69 @@ class TestPoolStorageSeam:
                                       arrs[name][3:7]), (storage, name)
             with pytest.raises(ValueError, match="retained window"):
                 pool.gather_block(2, 2)  # below the window base
+
+
+def random_slabs(rng, cap, d, b):
+    """Slabs of random bits in every field's dtype (float32 fields take
+    any bit pattern, NaN payloads included)."""
+    from repro.core import cmatrix
+    arrs = cmatrix.empty_node_arrays(cap, d, b)
+    return {name: rng.integers(0, 2**32, arr.shape, dtype=np.uint64)
+            .astype(np.uint32).view(arr.dtype)
+            for name, arr in arrs.items()}
+
+
+class TestJittedSlide:
+    """The device slide is one jitted program per pool capacity, and
+    leaves the whole capacity as the host slide does."""
+
+    @pytest.mark.parametrize("cap,n,k", [
+        pytest.param(8, 6, 2, id="middle"),
+        pytest.param(8, 8, 3, id="n_eq_cap"),
+        pytest.param(8, 5, 5, id="k_eq_n"),
+        pytest.param(8, 8, 8, id="k_eq_n_eq_cap"),
+        pytest.param(8, 7, 1, id="one_row"),
+        pytest.param(1, 1, 1, id="cap_one"),
+    ])
+    def test_device_slide_equals_host_slide(self, cap, n, k):
+        d, b = 4, 2
+        arrs = random_slabs(np.random.default_rng(cap * 100 + n * 10 + k),
+                            cap, d, b)
+        host = HostPoolStorage(d, b)
+        host.slabs = {name: a.copy() for name, a in arrs.items()}
+        host.cap = cap
+        dev = DevicePoolStorage(d, b)
+        dev.slabs = {name: jnp.asarray(a) for name, a in arrs.items()}
+        dev.cap = cap
+        host.slide(n, k)
+        dev.slide(n, k)
+        got = dev.host_view()
+        for name in NodeState._fields:
+            assert got[name].dtype == host.slabs[name].dtype, name
+            assert got[name].shape == (cap, d, d, b), name
+            np.testing.assert_array_equal(got[name].view(np.uint32),
+                                          host.slabs[name].view(np.uint32),
+                                          err_msg=name)
+
+    def test_one_program_per_capacity(self):
+        d, b, cap, k = 4, 2, 16, 3
+        arrs = random_slabs(np.random.default_rng(1), cap, d, b)
+        st = DevicePoolStorage(d, b)
+        _slide_slabs.clear_cache()
+        for n in (3, 7, 10, 15, 16):
+            st.slabs = {name: jnp.asarray(a) for name, a in arrs.items()}
+            st.cap = cap
+            st.slide(n, k)
+        assert _slide_slabs._cache_size() == 1
+
+    def test_slide_leaves_its_input_valid(self):
+        # the slide donates nothing: a caller may slide a copy of the
+        # live slab dict and keep reading the live arrays
+        d, b, cap = 4, 2, 8
+        arrs = random_slabs(np.random.default_rng(2), cap, d, b)
+        live = {name: jnp.asarray(a) for name, a in arrs.items()}
+        st = DevicePoolStorage(d, b)
+        st.slabs, st.cap = dict(live), cap
+        st.slide(6, 2)
+        for name in NodeState._fields:
+            np.testing.assert_array_equal(np.asarray(live[name]), arrs[name])
