@@ -20,6 +20,14 @@ Numbers compared, each with its limit:
   summary as it stood when the batch was submitted, or from an epoch no
   insert produced (a pin must see every closed edge): limit 0.
 * ``missing_answers``: batches that never got an answer: limit 0.
+
+The control (``run.py --control``) is the configuration with one
+fingerprint bit fewer (``F1 = 18``).  The check's bait ids
+(``traffic.bait_ids``) share their stream ids' whole key at ``F1 = 18``,
+so a summary with one fingerprint bit fewer adds the stream id's weight
+to each bait, whether its probes read the stored chain index or not, and
+``above_exact`` rises far above 0; at the configuration's ``F1`` a bait
+has a key, and candidate rows, of its own and answers exactly.
 """
 from __future__ import annotations
 

@@ -1,6 +1,7 @@
 """The plain reference agrees with the program where the program is
 known to be right, its leaf and retention replay matches the program's
 own bookkeeping, and its hashing is the program's."""
+import loader
 import numpy as np
 import pytest
 import streams
@@ -159,9 +160,37 @@ def test_twins_share_a_fingerprint_and_a_row_not_always_a_key():
     assert other.any() and (~other).any()
 
 
+@pytest.mark.parametrize("side", ["s", "d"])
+@pytest.mark.parametrize("config", ["wikitalk-window", "lkml"])
+def test_bait_ids_share_the_key_one_bit_below(config, side):
+    """At ``F1 - 1`` fingerprint bits a bait is its stream id's key; at
+    ``F1`` it has another key, the same fingerprint, candidate rows
+    disjoint from the stream id's, and no twin in the stream."""
+    cfg = loader.load_config(config)
+    sketch = cfg["sketch"]
+    n = 40_000
+    arrays = streams.EdgeStream(cfg["stream"], 8).arrays(n)
+    ids = arrays[0 if side == "s" else 1]
+    stream = Twins(ids, sketch, side)
+    rng = np.random.default_rng(2**31 + 5)
+    u = ids[rng.integers(0, n, 2000)]
+    b = traffic.bait_ids(u, sketch, side, stream, rng)
+    assert np.all(b != 0xFFFFFFFF)
+    coarse = dict(sketch, F1=sketch["F1"] - 1)
+    np.testing.assert_array_equal(key(b, coarse, side),
+                                  key(u, coarse, side))
+    assert np.all(key(b, sketch, side) != key(u, sketch, side))
+    f_b, rows_b = coords(b, sketch, side)
+    f_u, rows_u = coords(u, sketch, side)
+    np.testing.assert_array_equal(f_b, f_u)
+    assert not np.any(rows_b & rows_u)
+    qi, _ = stream.pairs(b)
+    assert len(qi) == 0
+
+
 def test_baits_split_one_fingerprint_bit():
     """A bait answers exactly at the configuration's F1 and takes its
-    stream id's weight one fingerprint bit below."""
+    stream id's whole weight one fingerprint bit below."""
     n = 40_000
     arrays = streams.EdgeStream(WIKI, 6).arrays(n)
     baits = traffic.Baits(arrays, n, SKETCH)
@@ -169,7 +198,7 @@ def test_baits_split_one_fingerprint_bit():
     u = arrays[0][rng.integers(0, n, 64)]
     b = baits.ids(u, "s", rng)
     assert np.all(b != 0xFFFFFFFF) and not np.isin(b, arrays[0]).any()
-    lo = (1 << 18) - 1
+    lo = (1 << 22) - 1
     h_u, h_b = mix32(u, SKETCH["seed"]), mix32(b, SKETCH["seed"])
     np.testing.assert_array_equal(h_u & lo, h_b & lo)
     ref = Reference(arrays, SKETCH, {"kind": "none"})
@@ -181,10 +210,11 @@ def test_baits_split_one_fingerprint_bit():
     for f1 in (19, 18):
         sk, _, _ = build(WIKI, n, {"kind": "none"}, seed=6, F1=f1)
         sk.flush()
-        (res,) = sk.query(to_queries([("out", b, 0, t_end)])).values
-        got[f1] = np.asarray(res)
-    np.testing.assert_array_equal(got[19], want)
-    assert (got[18] > 0).mean() > 0.5
+        got[f1] = [np.asarray(v) for v in sk.query(to_queries(
+            [("out", b, 0, t_end), ("out", u, 0, t_end)])).values]
+    np.testing.assert_array_equal(got[19][0], want)
+    np.testing.assert_array_equal(got[18][0], got[18][1])
+    assert np.all(got[18][0] > 0)
 
 
 def test_exact_index_sums_ranges():

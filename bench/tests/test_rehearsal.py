@@ -1,12 +1,15 @@
 """Every cell end to end on the CPU at its rehearsal size, with Pallas
 interpreted: the flow runs, the comparison holds, and no device result
-is ever printed.  Also: the control and the planted faults come out not
-correct, and a run without a TPU exits non-zero with no result."""
+is ever printed, with the probes as they are and as either probe plant
+(``faults.PROBES``) fixes them.  Also: the control and the planted faults
+come out not correct, and a run without a TPU exits non-zero with no
+result."""
 import json
 import os
 import subprocess
 import sys
 
+import faults
 import loader
 import pytest
 
@@ -43,9 +46,15 @@ def rehearsal_result(p) -> dict:
     return json.loads(lines[-1][len("rehearsal: "):])
 
 
+# the probes as they are, and as the plants fix them: the check has to
+# hold whichever the program adopts
+PROBES = (None, *faults.PROBES)
+
+
+@pytest.mark.parametrize("probes", PROBES, ids=lambda p: p or "as_is")
 @pytest.mark.parametrize("cell", CELLS)
-def test_cell_rehearses_correct(cell):
-    out = rehearsal_result(run(cell, "--rehearsal"))
+def test_cell_rehearses_correct(cell, probes):
+    out = rehearsal_result(run(cell, "--rehearsal", plant=probes))
     assert out["correct"], out["checks"]
     assert out["failed"] == 0 and out["attempted"] > 0
     assert out["checks"]["values_compared"]["value"] > 0
@@ -60,11 +69,15 @@ def test_without_a_tpu_no_result():
     assert "no TPU" in p.stderr
 
 
+@pytest.mark.parametrize("probes", PROBES, ids=lambda p: p or "as_is")
 @pytest.mark.parametrize("cell", CELLS)
-def test_control_is_not_correct(cell):
+def test_control_is_not_correct(cell, probes):
     """The control keeps one fingerprint bit fewer than the
-    configuration states: the baits take their stream ids' weight."""
-    out = rehearsal_result(run(cell, "--rehearsal", "--control"))
+    configuration states: the baits share their stream ids' whole key
+    there and take their weight, whether or not the probes read the
+    chain index."""
+    out = rehearsal_result(run(cell, "--rehearsal", "--control",
+                               plant=probes))
     assert not out["correct"]
     assert out["checks"]["above_exact"]["value"] > 0
 

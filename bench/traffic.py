@@ -15,9 +15,9 @@ has one, else the whole history).  Each edge and vertex query asks
 ``items`` edges or vertices drawn from the ``recent`` most recently
 arrived edges (a subgraph half as many), and besides ``bait`` vertex ids
 and edges that never occur in the stream, made from stream ids by the
-reference's hashing (``bait_ids``): a summary with one fingerprint bit
-fewer than the configuration states would answer with the weight of
-their stream id.
+reference's hashing (``bait_ids``): they share their stream ids' whole
+key at ``F1 - 1`` fingerprint bits, so a summary with one fingerprint
+bit fewer than the configuration states adds the stream id's weight.
 
 A batch is a list of plain queries ``(kind, ids, ts, te)``, with
 ``kind`` one of ``edge``, ``out``, ``in``, ``path``, ``subgraph`` and
@@ -26,37 +26,46 @@ A batch is a list of plain queries ``(kind, ids, ts, te)``, with
 from __future__ import annotations
 
 import numpy as np
-from reference import Twins, coords, mix32, side_seed, unmix32
+from reference import Twins, mix32, side_seed, unmix32
 
 KINDS = ("edge", "out", "in", "path", "subgraph")
 
 
 def bait_ids(u, sketch: dict, side: str, stream: Twins, rng,
              tries: int = 64) -> np.ndarray:
-    """For each stream id ``u[i]``, an id that shares its low ``F1 - 1``
-    hash bits and, at ``F1 - 1`` fingerprint bits, a candidate row; and
-    that at ``F1`` bits shares a fingerprint and a candidate row with no
-    id of ``stream``.  A sound summary answers it exactly; one that keeps
-    a fingerprint bit fewer adds ``u[i]``'s weight.  0xFFFFFFFF where no
-    such id was found in ``tries`` draws."""
+    """For each stream id ``u[i]``, an id that has ``u[i]``'s whole key
+    one fingerprint bit below the configuration and another key at it.
+
+    Its hash keeps ``u[i]``'s low ``F1 + log2(d1) - 1`` bits, flips the
+    next one, the top bit of the base row at ``F1`` bits, and draws the
+    bits above at random.  At ``F1 - 1`` fingerprint bits the key is the
+    low ``F1 + log2(d1) - 1`` bits, so the bait and ``u[i]`` are one
+    vertex there, probes that read the chain index included: a summary
+    with one fingerprint bit fewer adds ``u[i]``'s weight.  At ``F1``
+    bits the bait has ``u[i]``'s fingerprint and the base row ``d1 / 2``
+    away, whose candidate rows the linear-congruential chain keeps
+    disjoint from ``u[i]``'s.  A draw that shares a fingerprint and a
+    candidate row with an id of ``stream`` (of which ``u[i]`` is one) is
+    refused, so a sound summary answers it exactly, by the fingerprint
+    alone as well.
+    0xFFFFFFFF where no such id was found in ``tries`` draws."""
     u = np.asarray(u, np.uint32)
-    f = int(sketch["F1"])
+    f, d1 = int(sketch["F1"]), int(sketch["d1"])
+    if d1 & (d1 - 1):
+        raise ValueError(f"bait ids need a power-of-two d1, not {d1}")
+    top = f + d1.bit_length() - 2          # the base row's top bit at F1
     hu = mix32(u, side_seed(sketch, side))
-    low = hu & np.uint32((1 << (f - 1)) - 1)
-    flip = ((hu >> np.uint32(f - 1)) & np.uint32(1)) ^ np.uint32(1)
-    high = rng.integers(0, 1 << (32 - f), (len(u), tries),
+    low = hu & np.uint32((1 << top) - 1)
+    flip = ((hu >> np.uint32(top)) & np.uint32(1)) ^ np.uint32(1)
+    high = rng.integers(0, 1 << (31 - top), (len(u), tries),
                         dtype=np.uint64).astype(np.uint32)
-    h = (low[:, None] | (flip[:, None] << np.uint32(f - 1))
-         | (high << np.uint32(f)))
+    h = (low[:, None] | (flip[:, None] << np.uint32(top))
+         | (high << np.uint32(top + 1)))
     ids = unmix32(h, side_seed(sketch, side)).ravel()
-    coarse = dict(sketch, F1=f - 1)
-    _, rows_b = coords(ids, coarse, side)
-    _, rows_u = coords(u, coarse, side)
-    ok = (rows_b.reshape(len(u), tries) & rows_u[:, None]) != 0
     qi, _ = stream.pairs(ids)
     twin = np.zeros(len(ids), bool)
     twin[qi] = True
-    ok &= ~twin.reshape(len(u), tries)
+    ok = ~twin.reshape(len(u), tries)
     first = np.argmax(ok, axis=1)
     found = ok[np.arange(len(u)), first]
     return np.where(found, ids.reshape(len(u), tries)[np.arange(len(u)),
