@@ -196,6 +196,12 @@ class IngestStats:
       and the bytes of capacity each allocated.
     * ``slides`` — retention slides of a level pool (one per level a
       segment leaves).
+    * ``key_splits`` — items the host placement twin
+      (``cmatrix.place_entries_host``: the host engine's leaves and every
+      host-storage cascade level) kept apart from an entry with their
+      fingerprints at their address under another chain index, which a
+      fingerprint-only merge would have joined.  Device placement counts
+      none, so no device program gains an output.
     """
     inserts: int = 0
     drains: int = 0
@@ -208,6 +214,7 @@ class IngestStats:
     pool_grows: int = 0
     pool_grow_bytes: int = 0
     slides: int = 0
+    key_splits: int = 0
 
     def snapshot(self) -> dict:
         """The counters as a plain dict (subtract two for a delta)."""

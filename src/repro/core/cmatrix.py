@@ -13,6 +13,12 @@ Design notes (see DESIGN.md §3 for the TPU adaptation rationale):
   arrival order within a bucket).  Items that fail every mapping bucket are
   returned compacted for the caller's overflow block — nothing is dropped,
   so the one-sided error guarantee survives.
+* An entry belongs to a key only where its fingerprints match AND its
+  stored ``idx`` names the chain position at which the item (or query)
+  reached it: same fingerprints at one address under another chain
+  position are another key's (another base row).  Both the placement
+  merge (phase A) and the probes read ``idx``, so a summary is exact
+  over each id's F1 + log2(d1) bit key, never merging two keys.
 * Aggregation (paper Alg. 2) recovers each stored entry's leaf-level LCG
   chain in closed form from its (address, fingerprint, chain-index) triple,
   shifts R fingerprint bits per level into the address, and re-places the
@@ -116,7 +122,10 @@ def place_entries(node: NodeState, fs, fd, rows, cols, w, t, valid,
     """Place up to n items into one matrix.
 
     rows/cols: (n, r) candidate addresses at *this* level, lex probe order
-    (i, j) over the r x r mapping buckets.  Returns (node', placed (n,)).
+    (i, j) over the r x r mapping buckets.  At round k = i * r + j an item
+    merges only into an entry with its fingerprints (and ``t`` where
+    ``match_time``) stored with ``idx == k``; else it claims a free slot
+    there or goes on to round k + 1.  Returns (node', placed (n,)).
     """
     n = fs.shape[0]
     placed = ~valid
@@ -132,11 +141,12 @@ def place_entries(node: NodeState, fs, fd, rows, cols, w, t, valid,
         col = cols[:, j].astype(jnp.int32)
         active = ~placed
 
-        # --- phase A: merge into an existing matching entry -------------
+        # --- phase A: merge into an entry of this key, placed at round k -
         e_fs = state.fp_s[row, col]          # (n, b)
         e_fd = state.fp_d[row, col]
         e_t = state.t[row, col]
-        match = (e_fs == fs[:, None]) & (e_fd == fd[:, None]) & (e_fs != EMPTY)
+        match = (e_fs == fs[:, None]) & (e_fd == fd[:, None]) & \
+            (e_fs != EMPTY) & (state.idx[row, col] == jnp.uint32(k))
         if match_time:
             match &= e_t == t[:, None]
         has_match = jnp.any(match, axis=-1) & active
@@ -331,15 +341,22 @@ def _premerge_host(w, valid, order, same):
 
 
 def place_entries_host(state4, wmat, fs, fd, rows, cols, w, t, valid,
-                       orders, *, d: int, b: int, r: int, match_time: bool):
+                       orders, *, d: int, b: int, r: int, match_time: bool,
+                       stats=None):
     """NumPy twin of :func:`place_entries_pre`: phase-exact placement on
     the host.  On CPU backends this outruns the XLA scatter/gather path
     (no dispatch, no transfers, C-speed fancy indexing) while producing
     the same matrices; accumulation order matches the device scatters
     (``np.add.at`` processes updates in index order).
+
+    With ``stats`` (an :class:`~repro.api.queries.IngestStats`), adds to
+    ``stats.key_splits`` the items that met an entry with their
+    fingerprints at their address under another chain index (another
+    key's entry, which a fingerprint-only merge would have joined).
     """
     n = fs.shape[0]
     placed = ~valid
+    split = np.zeros((n,), bool)
     t = np.asarray(t, np.uint32)
     w = np.asarray(w, np.float32)
     for k in range(r * r):
@@ -350,18 +367,23 @@ def place_entries_host(state4, wmat, fs, fd, rows, cols, w, t, valid,
         col = cols[:, j].astype(np.int64)
         active = ~placed
 
-        # phase A: merge
-        e_fs = state4[0, row, col]
-        e_fd = state4[1, row, col]
-        match = (e_fs == fs[:, None]) & (e_fd == fd[:, None]) & \
-            (e_fs != EMPTY)
+        # phase A: merge into an entry of this key, placed at round k.
+        # Only active items are gathered; matched weights are added in
+        # index order, as the device scatter adds them (its other
+        # updates add 0.0)
+        act = np.nonzero(active)[0]
+        ra, ca = row[act], col[act]
+        e = state4[:, ra, ca]                      # (4, n_active, b)
+        twin = (e[0] == fs[act, None]) & (e[1] == fd[act, None]) & \
+            (e[0] != EMPTY)
         if match_time:
-            match &= state4[2, row, col] == t[:, None]
-        has_match = match.any(axis=-1) & active
-        slot = match.argmax(axis=-1)
-        add_w = np.where(has_match, w, np.float32(0.0))
-        np.add.at(wmat, (row, col, slot), add_w)
-        placed = placed | has_match
+            twin &= e[2] == t[act, None]
+        match = twin & (e[3] == np.uint32(k))
+        merged = match.any(axis=-1)
+        split[act[twin.any(axis=-1) & ~merged]] = True
+        np.add.at(wmat, (ra[merged], ca[merged],
+                         match[merged].argmax(axis=-1)), w[act[merged]])
+        placed[act[merged]] = True
         active = ~placed
 
         # phase B: claim free slots, arrival order within a bucket
@@ -392,6 +414,8 @@ def place_entries_host(state4, wmat, fs, fd, rows, cols, w, t, valid,
             state4[3, ra, ca, tgt] = np.uint32(k)
             wmat[ra, ca, tgt] += w[a]          # distinct targets
             placed[a] = True
+    if stats is not None:
+        stats.key_splits += int(split.sum())
     return state4, wmat, placed & valid
 
 
@@ -403,9 +427,9 @@ def _empty_state4_host(d: int, b: int):
 
 
 def insert_chunks_host(fs, fd, rows, cols, w, t, valid, pm_order, pm_same,
-                       orders, params: HiggsParams):
+                       orders, params: HiggsParams, stats=None):
     """Host twin of :func:`insert_chunks_pre` (same stacked signature and
-    returns, numpy arrays)."""
+    returns, numpy arrays); ``stats`` as :func:`place_entries_host`."""
     d, b = params.d1, params.b
     r = params.r if params.use_mmb else 1
     L, n = fs.shape
@@ -418,15 +442,17 @@ def insert_chunks_host(fs, fd, rows, cols, w, t, valid, pm_order, pm_same,
         w_m[i] = wm
         _, _, placed = place_entries_host(
             state4[i], wmat[i], fs[i], fd[i], rows[i], cols[i], wm, t[i],
-            vm, orders[i], d=d, b=b, r=r, match_time=True)
+            vm, orders[i], d=d, b=b, r=r, match_time=True, stats=stats)
         spill[i] = vm & ~placed
     return state4, wmat, spill, w_m
 
 
 def aggregate_children_host(fp_s_p, fp_d_p, rows_p, cols_p, w, valid,
-                            orders, params: HiggsParams, level: int):
+                            orders, params: HiggsParams, level: int,
+                            stats=None):
     """Host twin of :func:`aggregate_children_pre` (same stacked
-    signature and returns, numpy arrays)."""
+    signature and returns, numpy arrays); ``stats`` as
+    :func:`place_entries_host`."""
     b = params.b
     r = params.r if params.use_mmb else 1
     dp = params.d(level + 1)
@@ -439,7 +465,7 @@ def aggregate_children_host(fp_s_p, fp_d_p, rows_p, cols_p, w, valid,
         _, _, placed = place_entries_host(
             state4[i], wmat[i], fp_s_p[i], fp_d_p[i], rows_p[i], cols_p[i],
             w[i].astype(np.float32), t0, valid[i], orders[i],
-            d=dp, b=b, r=r, match_time=False)
+            d=dp, b=b, r=r, match_time=False, stats=stats)
         spill[i] = valid[i] & ~placed
     return state4, wmat, spill
 
@@ -484,10 +510,11 @@ def place_entries_pre(state4, wmat, fs, fd, rows, cols, w, t, valid, orders,
         col = jnp.take(cols, j, axis=1)
         active = ~placed
 
-        # --- phase A: merge into an existing matching entry -------------
+        # --- phase A: merge into an entry of this key, placed at round k -
         g = state4[:, row, col]                    # (4, n, b)
         e_fs, e_fd, e_t = g[0], g[1], g[2]
-        match = (e_fs == fs[:, None]) & (e_fd == fd[:, None]) & (e_fs != EMPTY)
+        match = (e_fs == fs[:, None]) & (e_fd == fd[:, None]) & \
+            (e_fs != EMPTY) & (g[3] == k.astype(jnp.uint32))
         if match_time:
             match &= e_t == t[:, None]
         has_match = jnp.any(match, axis=-1) & active
@@ -729,34 +756,36 @@ def aggregate_children_pre(fp_s_p, fp_d_p, rows_p, cols_p, w, valid, orders,
 
 def probe_edge(nodes: NodeState, node_mask, fs, fd, rows, cols, ts, te, *,
                match_time: bool):
-    """Sum of matching entry weights for a batch of edge queries over a
-    batch of matrices.
+    """Sum of the weights of a batch of edge queries' entries over a batch
+    of matrices.
 
     nodes: stacked NodeState with leading axis m; node_mask: (m,) bool for
     padded node lists.
     fs/fd: (q,), rows/cols: (q, r), ts/te: scalars or (q,).
     Returns (q,) float32.
 
-    Contract: each query's candidate row/col lists are duplicate-free
-    (guaranteed by the full-period LCG chains for r <= d); duplicated
-    candidates would double count here while the Pallas one-hot probe
-    dedups them.
+    An entry at candidate bucket (rows[a], cols[c]) is the query's only
+    where its fingerprints match and its stored ``idx`` is ``a * r + c``,
+    the chain position the query reached it at: the same fingerprints
+    under another position belong to another base row, another key.
+    Each entry is therefore counted once, even for repeated candidates.
     """
     q, r = rows.shape
     wmask = jnp.where(node_mask, 1.0, 0.0)[:, None, None, None]
+    pos = (jnp.arange(r, dtype=jnp.uint32)[:, None] * r
+           + jnp.arange(r, dtype=jnp.uint32)[None, :])[None, :, :, None]
 
     def one(fs_i, fd_i, row_i, col_i, ts_i, te_i):
         # (m, r, r, b) gathered buckets
-        efs = nodes.fp_s[:, row_i[:, None], col_i[None, :], :]
-        efd = nodes.fp_d[:, row_i[:, None], col_i[None, :], :]
-        ew = nodes.w[:, row_i[:, None], col_i[None, :], :]
+        at = (slice(None), row_i[:, None], col_i[None, :])
         # EMPTY (0xFFFFFFFF) can never equal an F-bit fingerprint, so the
         # equality test alone excludes free entries.
-        match = (efs == fs_i) & (efd == fd_i)
+        match = (nodes.fp_s[at] == fs_i) & (nodes.fp_d[at] == fd_i) & \
+            (nodes.idx[at] == pos)
         if match_time:
-            et = nodes.t[:, row_i[:, None], col_i[None, :], :]
+            et = nodes.t[at]
             match &= (et >= ts_i) & (et <= te_i)
-        return jnp.sum(jnp.where(match, ew * wmask, 0.0))
+        return jnp.sum(jnp.where(match, nodes.w[at] * wmask, 0.0))
 
     ts = jnp.broadcast_to(jnp.asarray(ts, jnp.uint32), (q,))
     te = jnp.broadcast_to(jnp.asarray(te, jnp.uint32), (q,))
@@ -769,20 +798,26 @@ def probe_vertex(nodes: NodeState, node_mask, fv, rows, ts, te, *,
     """Vertex query: sum weights over r candidate rows (source direction)
     or columns (destination direction) across m matrices.
 
-    fv: (q,), rows: (q, r).  Returns (q,) float32.
+    fv: (q,), rows: (q, r).  Returns (q,) float32.  An entry in candidate
+    row ``rows[a]`` is the query's only where its fingerprint matches and
+    its stored chain index names position ``a`` on that side:
+    ``idx // r == a`` (out) or ``idx % r == a`` (in).
     """
+    r = rows.shape[1]
     wmask = jnp.where(node_mask, 1.0, 0.0)[:, None, None, None]
+    pos = jnp.arange(r, dtype=jnp.uint32)[None, :, None, None]
 
     def one(fv_i, row_i):
         if direction == "out":
-            efp = nodes.fp_s[:, row_i, :, :]       # (m, r, d, b)
-            ew = nodes.w[:, row_i, :, :]
-            et = nodes.t[:, row_i, :, :]
+            efp, ew, et, ei = (a[:, row_i, :, :] for a in (
+                nodes.fp_s, nodes.w, nodes.t, nodes.idx))   # (m, r, d, b)
+            at_pos = ei // r == pos
         else:
-            efp = nodes.fp_d[:, :, row_i, :].transpose(0, 2, 1, 3)
-            ew = nodes.w[:, :, row_i, :].transpose(0, 2, 1, 3)
-            et = nodes.t[:, :, row_i, :].transpose(0, 2, 1, 3)
-        match = efp == fv_i                        # EMPTY never matches
+            efp, ew, et, ei = (a[:, :, row_i, :].transpose(0, 2, 1, 3)
+                               for a in (nodes.fp_d, nodes.w, nodes.t,
+                                         nodes.idx))
+            at_pos = ei % r == pos
+        match = (efp == fv_i) & at_pos             # EMPTY never matches
         if match_time:
             match &= (et >= ts) & (et <= te)
         return jnp.sum(jnp.where(match, ew * wmask, 0.0))

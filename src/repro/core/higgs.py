@@ -640,54 +640,59 @@ class HiggsSketch(LegacyQueryMixin):
             # all happen on device against the persistent pool slabs
             self._close_leaves_fused(buf, spans)
             return
-        hs_full = hashing.np_mix32(buf[0, s0:s_end], p.seed)
-        hd_full = hashing.np_mix32(buf[1, s0:s_end], p.seed ^ 0x5BD1E995)
-        w_full = np.ascontiguousarray(buf[2, s0:s_end]).view(np.float32)
-        t_full = buf[3, s0:s_end]
+        with _span("higgs.drain.prep"):
+            hs_full = hashing.np_mix32(buf[0, s0:s_end], p.seed)
+            hd_full = hashing.np_mix32(buf[1, s0:s_end],
+                                       p.seed ^ 0x5BD1E995)
+            w_full = np.ascontiguousarray(buf[2, s0:s_end]).view(np.float32)
+            t_full = buf[3, s0:s_end]
 
-        max_len = max(e - s for s, e in spans)
-        pad = max(self._chunk_pad, _pow2_pad(max_len, lo=64))
-        # the jitted backends pow2-pad the leaf axis too (all-invalid
-        # rows, discarded below) so varying drain sizes don't trigger a
-        # recompile per distinct leaf count; the host engine has no
-        # compile cache and takes the exact count
-        lead = nl if self._backend == "host" else _pow2_pad(nl, lo=1)
-        hs = np.zeros((lead, pad), np.uint32)
-        hd = np.zeros((lead, pad), np.uint32)
-        w = np.zeros((lead, pad), np.float32)
-        t = np.zeros((lead, pad), np.uint32)
-        valid = np.zeros((lead, pad), bool)
-        for i, (s, e) in enumerate(spans):
-            m = e - s
-            hs[i, :m] = hs_full[s - s0:e - s0]
-            hd[i, :m] = hd_full[s - s0:e - s0]
-            w[i, :m] = w_full[s - s0:e - s0]
-            t[i, :m] = t_full[s - s0:e - s0]
-            valid[i, :m] = True
+            max_len = max(e - s for s, e in spans)
+            pad = max(self._chunk_pad, _pow2_pad(max_len, lo=64))
+            # the jitted backends pow2-pad the leaf axis too (all-invalid
+            # rows, discarded below) so varying drain sizes don't trigger
+            # a recompile per distinct leaf count; the host engine has no
+            # compile cache and takes the exact count
+            lead = nl if self._backend == "host" else _pow2_pad(nl, lo=1)
+            hs = np.zeros((lead, pad), np.uint32)
+            hd = np.zeros((lead, pad), np.uint32)
+            w = np.zeros((lead, pad), np.float32)
+            t = np.zeros((lead, pad), np.uint32)
+            valid = np.zeros((lead, pad), bool)
+            for i, (s, e) in enumerate(spans):
+                m = e - s
+                hs[i, :m] = hs_full[s - s0:e - s0]
+                hd[i, :m] = hd_full[s - s0:e - s0]
+                w[i, :m] = w_full[s - s0:e - s0]
+                t[i, :m] = t_full[s - s0:e - s0]
+                valid[i, :m] = True
+            if self._backend != "pallas":
+                fs, fd, rows, cols = cmatrix.host_leaf_coords(hs, hd, p)
+                pm_order, pm_same = cmatrix.host_premerge_meta(hs, hd, t,
+                                                               valid)
+                r = p.r if p.use_mmb else 1
+                orders = cmatrix.host_round_orders(rows, cols, p.d1, r)
 
         if self._backend == "pallas":
             host, spill_mask, w_sp = self._insert_leaves_pallas(
                 hs, hd, w, t, valid)
         else:
-            fs, fd, rows, cols = cmatrix.host_leaf_coords(hs, hd, p)
-            pm_order, pm_same = cmatrix.host_premerge_meta(hs, hd, t, valid)
-            r = p.r if p.use_mmb else 1
-            orders = cmatrix.host_round_orders(rows, cols, p.d1, r)
             if self._backend == "host":
-                state4, wmat, spill, w_merged = cmatrix.insert_chunks_host(
+                state4, wmat, spill_mask, w_sp = cmatrix.insert_chunks_host(
                     fs, fd, rows, cols, w, t, valid, pm_order, pm_same,
-                    orders, p)
+                    orders, p, stats=self.ingest_stats)
             else:
-                state4, wmat, spill, w_merged = cmatrix.insert_chunks_pre(
-                    jnp.asarray(fs), jnp.asarray(fd), jnp.asarray(rows),
-                    jnp.asarray(cols), jnp.asarray(w), jnp.asarray(t),
-                    jnp.asarray(valid), jnp.asarray(pm_order),
-                    jnp.asarray(pm_same), jnp.asarray(orders), p)
-            s4 = np.asarray(state4)
-            host = {"fp_s": s4[:, 0], "fp_d": s4[:, 1], "t": s4[:, 2],
-                    "idx": s4[:, 3], "w": np.asarray(wmat)}
-            spill_mask = np.asarray(spill)
-            w_sp = np.asarray(w_merged)
+                # the whole leaf state comes back to the host pools: the
+                # drain's one blocking copy on this engine
+                state4, wmat, spill_mask, w_sp = _fetch(
+                    cmatrix.insert_chunks_pre(
+                        jnp.asarray(fs), jnp.asarray(fd), jnp.asarray(rows),
+                        jnp.asarray(cols), jnp.asarray(w), jnp.asarray(t),
+                        jnp.asarray(valid), jnp.asarray(pm_order),
+                        jnp.asarray(pm_same), jnp.asarray(orders), p),
+                    self.ingest_stats)
+            host = {"fp_s": state4[:, 0], "fp_d": state4[:, 1],
+                    "t": state4[:, 2], "idx": state4[:, 3], "w": wmat}
 
         base = self.pools[0].base + self.pools[0].append_batch(host, nl)
         self.ingest_stats.leaves_closed += nl
@@ -865,13 +870,38 @@ class HiggsSketch(LegacyQueryMixin):
         if self._storage == "device":
             self._build_parents_fused(level, u0, m)
             return
+        with _span("higgs.cascade.ob"):
+            ob = self._gather_child_obs_stacked(level, u0, m)
+        with _span("higgs.cascade.host"):
+            host, spill, f1s, f1d, base_s, base_d, w_all = \
+                self._build_level_host(level, u0, m, ob)
+        # covered by the leaf-closing bump earlier in this drain
+        self.pools[level].append_batch(host, m)  # higgslint: disable=R5
+        if not spill.any():
+            return
+        self.ingest_stats.spill_items += int(spill.sum())
+        with _span("higgs.drain.spill"):
+            for i in range(m):
+                idxs = np.nonzero(spill[i])[0]
+                if len(idxs):
+                    self.ob.add(level + 1, u0 + i,
+                                f1s=f1s[i, idxs], f1d=f1d[i, idxs],
+                                bs=base_s[i, idxs], bd=base_d[i, idxs],
+                                w=w_all[i, idxs].astype(np.float64),
+                                t=np.zeros((len(idxs),), np.uint32))
+
+    def _build_level_host(self, level: int, u0: int, m: int, ob):
+        """The numpy level build under host storage: recover the
+        children's leaf coordinates, append their overflow items ``ob``,
+        derive parent-level chains and round orders, and place the ``m``
+        parents.  Returns (parent field arrays, spill mask (m, N), and the
+        canonical columns f1s, f1d, base_s, base_d, w of every item)."""
         p = self.params
         theta = p.theta
         pool = self.pools[level - 1]
-        # bulk child gather through the pool API: one contiguous block
-        # fetch (a bounded d2h barrier under device storage, plain
-        # views under host storage); gather_block translates global
-        # parent-child ids to window-physical slots internally
+        # bulk child gather through the pool API: plain views of the
+        # host pool; gather_block translates global parent-child ids to
+        # window-physical slots internally
         blk = pool.gather_block(u0 * theta, m * theta)
         d = pool.d
         per = theta * d * d * pool.b
@@ -897,8 +927,6 @@ class HiggsSketch(LegacyQueryMixin):
             e_col, e_fd, e_idx, level, p, "d")
         w_all = e_w.astype(np.float32)
 
-        with _span("higgs.cascade.ob"):
-            ob = self._gather_child_obs_stacked(level, u0, m)
         if ob is not None:
             f1s = np.concatenate([f1s, ob["f1s"]], axis=1)
             f1d = np.concatenate([f1d, ob["f1d"]], axis=1)
@@ -924,25 +952,10 @@ class HiggsSketch(LegacyQueryMixin):
         # inside the fused device step (kernels.aggregate_fused)
         state4, wmat, spill = cmatrix.aggregate_children_host(
             fp_s_p, fp_d_p, rows_p, cols_p, w_all, e_valid, orders,
-            p, level)
-        s4 = np.asarray(state4)
-        host = {"fp_s": s4[:, 0], "fp_d": s4[:, 1], "t": s4[:, 2],
-                "idx": s4[:, 3], "w": np.asarray(wmat)}
-        # covered by the leaf-closing bump earlier in this drain
-        self.pools[level].append_batch(host, m)  # higgslint: disable=R5
-        spill_h = np.asarray(spill)
-        if not spill_h.any():
-            return
-        self.ingest_stats.spill_items += int(spill_h.sum())
-        with _span("higgs.drain.spill"):
-            for i in range(m):
-                idxs = np.nonzero(spill_h[i])[0]
-                if len(idxs):
-                    self.ob.add(level + 1, u0 + i,
-                                f1s=f1s[i, idxs], f1d=f1d[i, idxs],
-                                bs=base_s[i, idxs], bd=base_d[i, idxs],
-                                w=w_all[i, idxs].astype(np.float64),
-                                t=np.zeros((len(idxs),), np.uint32))
+            p, level, stats=self.ingest_stats)
+        host = {"fp_s": state4[:, 0], "fp_d": state4[:, 1],
+                "t": state4[:, 2], "idx": state4[:, 3], "w": wmat}
+        return host, spill, f1s, f1d, base_s, base_d, w_all
 
     def _build_parents_fused(self, level: int, u0: int, m: int) -> None:
         """Device-resident aggregation cascade step (device pool storage).

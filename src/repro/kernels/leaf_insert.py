@@ -18,8 +18,11 @@ slot's key is ``probe*2b + slot`` when it matches the edge and
 ``probe*2b + b + slot`` when it is empty, so the minimum key is exactly
 the slot that Algorithm 1's probe loop would stop at (first bucket in
 probe order holding a match or a free slot; a match beats a free slot
-within the bucket).  The uint32 fields are bit-cast to int32 around the
-call: only equality is ever tested on them.
+within the bucket).  A match is an entry with the edge's fingerprints and
+timestamp whose stored chain index is the probe index the edge reached it
+at: the same fingerprints under another index are another key's.  The
+uint32 fields are bit-cast to int32 around the call: only equality is
+ever tested on them.
 """
 from __future__ import annotations
 
@@ -69,9 +72,10 @@ def _kernel(pk_ref, fps_in, fpd_in, wm_in, tm_in, idx_in,
             cj = jnp.where(col_of == pk_ref[0, 5 + r + i, e], i, cj)
         k = ri * r + cj                                  # probe index
         cand = (ri >= 0) & (cj >= 0)
-        bfs, bfd, bt = fps_ref[0], fpd_ref[0], tm_ref[0]
+        bfs, bfd, bt, bidx = fps_ref[0], fpd_ref[0], tm_ref[0], idx_ref[0]
         empty = bfs == -1                                # EMPTY as int32
-        match = (bfs == fs) & (bfd == fd) & (bt == tv) & ~empty
+        # an entry is this edge's key only if placed at this probe index
+        match = (bfs == fs) & (bfd == fd) & (bt == tv) & (bidx == k) & ~empty
         key = jnp.where(cand & match, k * (2 * b) + slot,
                         jnp.where(cand & empty, k * (2 * b) + b + slot,
                                   _NO_SLOT))
@@ -84,7 +88,7 @@ def _kernel(pk_ref, fps_in, fpd_in, wm_in, tm_in, idx_in,
         fps_ref[0] = jnp.where(ins, fs, bfs)
         fpd_ref[0] = jnp.where(ins, fd, bfd)
         tm_ref[0] = jnp.where(ins, tv, bt)
-        idx_ref[0] = jnp.where(ins, k, idx_ref[0])
+        idx_ref[0] = jnp.where(ins, k, bidx)
         spilled = jnp.where(is_valid & ~placed, 1, 0)
         spill_ref[0] = jnp.where(edge_of == e, spilled, spill_ref[0])
         return 0

@@ -14,6 +14,11 @@ lane-dense ``(d, d*b)`` tile (element ``(row, col*b + slot)``), the
 query scalars and the node mask live in SMEM, and the ``(1, q)`` output
 row accumulates across the whole grid.
 
+An entry is a query's only where its fingerprint matches and its stored
+chain index ``idx`` names the candidate position the query met it at, as
+in :func:`repro.core.cmatrix.probe_edge`, so the ``idx`` slab streams in
+beside the others.
+
 Grid: (m, d / TR).
 """
 from __future__ import annotations
@@ -29,19 +34,26 @@ from repro.core.cmatrix import NodeState
 from repro.kernels.leaf_insert import default_interpret
 
 
-def _row_mask(ch_ref, off, j, r, tr, row0):
-    """(tr, 1): rows of this tile that are among query j's candidates
-    ``ch_ref[off:off + r, j]``."""
+def _chain_pos(ch_ref, off, j, r, of):
+    """Chain position of each row or column ``of`` among query j's
+    candidates ``ch_ref[off:off + r, j]`` (-1: not a candidate); a
+    repeated candidate keeps its first position, as insertion does."""
+    pos = jnp.full(of.shape, -1, jnp.int32)
+    for i in reversed(range(r)):
+        pos = jnp.where(of == ch_ref[off + i, j], i, pos)
+    return pos
+
+
+def _row_pos(ch_ref, off, j, r, tr, row0):
+    """(tr, 1): chain position of each row of this tile."""
     row_of = jax.lax.broadcasted_iota(jnp.int32, (tr, 1), 0) + row0
-    return functools.reduce(jnp.logical_or,
-                            [row_of == ch_ref[off + i, j] for i in range(r)])
+    return _chain_pos(ch_ref, off, j, r, row_of)
 
 
-def _col_mask(ch_ref, off, j, r, b, d):
-    """(1, d*b): lanes whose column is among query j's candidates."""
+def _col_pos(ch_ref, off, j, r, b, d):
+    """(1, d*b): chain position of each lane's column."""
     col_of = jax.lax.broadcasted_iota(jnp.int32, (1, d * b), 1) // b
-    return functools.reduce(jnp.logical_or,
-                            [col_of == ch_ref[off + i, j] for i in range(r)])
+    return _chain_pos(ch_ref, off, j, r, col_of)
 
 
 def _probe_grid(mask_ref, tt_ref, out_ref, match_time, mt_ref, hit_of):
@@ -74,31 +86,39 @@ def _probe_grid(mask_ref, tt_ref, out_ref, match_time, mt_ref, hit_of):
 
 
 def _edge_kernel(mask_ref, fp_ref, ch_ref, tt_ref, mfs_ref, mfd_ref, mw_ref,
-                 mt_ref, out_ref, *, match_time: bool, tr: int, r: int,
-                 b: int):
+                 mt_ref, midx_ref, out_ref, *, match_time: bool, tr: int,
+                 r: int, b: int):
     def hit_of(j, ti):
         mfs, mfd = mfs_ref[0], mfd_ref[0]
         d = mfs.shape[1] // b
+        ri = _row_pos(ch_ref, 0, j, r, tr, ti * tr)
+        cj = _col_pos(ch_ref, r, j, r, b, d)
+        # the entry is the query's only at the chain position it met it
         hit = ((mfs == fp_ref[0, j]) & (mfd == fp_ref[1, j])
-               & _row_mask(ch_ref, 0, j, r, tr, ti * tr)
-               & _col_mask(ch_ref, r, j, r, b, d))
+               & (ri >= 0) & (cj >= 0) & (midx_ref[0] == ri * r + cj))
         return hit, mw_ref[0]
 
     _probe_grid(mask_ref, tt_ref, out_ref, match_time, mt_ref, hit_of)
 
 
 def _vertex_kernel(mask_ref, fp_ref, ch_ref, tt_ref, mfp_ref, mw_ref, mt_ref,
-                   out_ref, *, match_time: bool, tr: int, r: int, b: int,
-                   direction: str):
+                   midx_ref, out_ref, *, match_time: bool, tr: int, r: int,
+                   b: int, direction: str):
     def hit_of(j, ti):
-        mfp = mfp_ref[0]
+        mfp, midx = mfp_ref[0], midx_ref[0]
         if direction == "out":
-            # candidates restrict the first matrix axis (tiled)
-            pos = _row_mask(ch_ref, 0, j, r, tr, ti * tr)
+            # candidates restrict the first matrix axis (tiled); the
+            # entry's row position is idx // r
+            pos = _row_pos(ch_ref, 0, j, r, tr, ti * tr)
+            at = (pos >= 0) & (midx >= pos * r) & (midx < pos * r + r)
         else:
-            # candidates restrict the second (column) axis (not tiled)
-            pos = _col_mask(ch_ref, 0, j, r, b, mfp.shape[1] // b)
-        return (mfp == fp_ref[0, j]) & pos, mw_ref[0]
+            # candidates restrict the second (column) axis (not tiled);
+            # the entry's column position is idx % r
+            pos = _col_pos(ch_ref, 0, j, r, b, mfp.shape[1] // b)
+            at = functools.reduce(jnp.logical_or,
+                                  [midx == i * r + pos for i in range(r)])
+            at &= pos >= 0
+        return (mfp == fp_ref[0, j]) & at, mw_ref[0]
 
     _probe_grid(mask_ref, tt_ref, out_ref, match_time, mt_ref, hit_of)
 
@@ -141,7 +161,8 @@ def edge_probe_pallas(nodes: NodeState, node_mask, fs, fd, rows, cols,
                           jnp.asarray(cols, jnp.int32).T])
     kernel = functools.partial(_edge_kernel, match_time=match_time, r=r)
     return _probe_call(kernel, nodes,
-                       (nodes.fp_s, nodes.fp_d, nodes.w, nodes.t),
+                       (nodes.fp_s, nodes.fp_d, nodes.w, nodes.t,
+                        nodes.idx.astype(jnp.int32)),
                        node_mask, fp, ch, ts, te, interpret)
 
 
@@ -158,5 +179,6 @@ def vertex_probe_pallas(nodes: NodeState, node_mask, fv, rows, ts, te, *,
     kernel = functools.partial(_vertex_kernel, match_time=match_time, r=r,
                                direction=direction)
     fpm = nodes.fp_s if direction == "out" else nodes.fp_d
-    return _probe_call(kernel, nodes, (fpm, nodes.w, nodes.t), node_mask,
-                       fp, ch, ts, te, interpret)
+    return _probe_call(kernel, nodes,
+                       (fpm, nodes.w, nodes.t, nodes.idx.astype(jnp.int32)),
+                       node_mask, fp, ch, ts, te, interpret)

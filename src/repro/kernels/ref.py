@@ -2,7 +2,8 @@
 
 * ``seq_insert_ref``: the paper's Algorithm 1, verbatim sequential
   semantics (per-edge probe of the r x r mapping buckets in lex order,
-  merge on (fp_s, fp_d, t) match, first empty slot, spill on full).  The
+  merge on an (fp_s, fp_d, t) match stored at this probe's chain index
+  k, first empty slot, spill on full).  The
   ``leaf_insert`` kernel must match this bit-for-bit.
 * ``edge_probe_ref`` / ``vertex_probe_ref``: the batched probe reference —
   thin wrappers over :mod:`repro.core.cmatrix`.
@@ -38,7 +39,8 @@ def seq_insert_ref(node: NodeState, fs, fd, rows, cols, w, t, valid,
             row, col = int(rows[e, i]), int(cols[e, j])
             bucket_fs = fps[row, col]
             match = ((bucket_fs == fs[e]) & (fpd[row, col] == fd[e]) &
-                     (tm[row, col] == t[e]) & (bucket_fs != EMPTY))
+                     (tm[row, col] == t[e]) & (idxm[row, col] == k) &
+                     (bucket_fs != EMPTY))
             hit = np.nonzero(match)[0]
             if hit.size:
                 wm[row, col, hit[0]] += w[e]

@@ -234,3 +234,34 @@ def test_fetch_counts_one_copy_and_its_bytes():
                         jax.numpy.ones(2, jax.numpy.float32)), st)
     assert [a.dtype for a in pair] == [np.uint32, np.float32]
     assert (st.fetches, st.fetch_bytes) == (2, 56)
+
+
+@pytest.mark.parametrize("backend", ["vector", "host"])
+def test_default_engine_spans_and_counters(backend, stream, tmp_path):
+    """Host pools, as ``auto`` resolves on a TPU (vector) and on the CPU
+    (host): the leaf prep and the numpy level build are spans of the
+    drain.  On the vector engine the leaf state's copy back to the host
+    pools is the drain's one fetch; the host engine fetches nothing."""
+    p = params(insert_backend=backend, pool_storage="host",
+               retention=RetentionPolicy())
+    sk = HiggsSketch(p)
+    d = str(tmp_path / backend)
+    jax.profiler.start_trace(d)
+    try:
+        for c in range(0, 3 * BATCH, BATCH):
+            sk.insert(*(a[c:c + BATCH] for a in stream))
+    finally:
+        jax.profiler.stop_trace()
+    spans = host_spans(d)
+    by = {n: [s for s in spans if s[0] == n] for n, _, _ in spans}
+    st = sk.ingest_stats
+    assert len(by["higgs.drain.prep"]) == st.drains == 3
+    assert len(by["higgs.cascade.host"]) == len(by["higgs.cascade"]) > 0
+    for sp in by["higgs.drain.prep"]:
+        assert inside(sp, by["higgs.drain"])
+    for sp in by["higgs.cascade.host"]:
+        assert inside(sp, by["higgs.cascade"])
+    want = st.drains if backend == "vector" else 0
+    assert st.fetches == len(by.get("higgs.fetch", [])) == want
+    assert (st.fetch_bytes > 0) == (backend == "vector")
+    assert st.launches == 0
